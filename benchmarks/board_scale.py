@@ -175,6 +175,8 @@ def main(boards=("1x1", "2x2", "4x6", "4x12"), chip: str = "4x2",
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--boards", default="1x1,2x2,4x6,4x12",

@@ -278,6 +278,8 @@ def sweep(sizes=(256, 1024, 4096), n_ticks: int = 64,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sweep", default=None, metavar="SIZES",

@@ -11,7 +11,8 @@ RESULTS: list[dict] = []
 
 
 def time_call(fn, *args, warmup=1, iters=3, **kw):
-    """Median wall time per call in microseconds (CPU, interpret-mode)."""
+    """Median host wall time per call in microseconds, on whatever
+    backend JAX runs (on the CPU, Pallas kernels run interpreted)."""
     for _ in range(warmup):
         jax.block_until_ready(fn(*args, **kw))
     ts = []

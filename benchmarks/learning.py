@@ -118,6 +118,8 @@ def main(n_channels: int = 6, n_neurons: int = 100, n_ticks: int = 2048,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--channels", type=int, default=6)

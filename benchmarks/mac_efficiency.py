@@ -1,7 +1,7 @@
 """Fig. 14 + Fig. 15: PE CoreMark efficiency and MAC-array matrix-multiply
 energy efficiency at the DVFS performance levels.
 
-The kernel's correctness is executed (interpret mode); energy derives from
+The kernel is executed to check its correctness; energy derives from
 the cycle model (core/pe.py) + the paper's measured operating points.
 Checks: modeled TOPS/W lands on the measured 1.47 / 1.51 (and 1.75 at the
 0.5 V / 320 MHz point) within 10%, including the paper's 1.56x data-path
@@ -61,4 +61,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     main()

@@ -46,4 +46,6 @@ def main(n_neurons: int = 512, ticks: int = 1200) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     main()

@@ -126,6 +126,8 @@ def main(fleet: int = 64, sessions: int = 96, rate: float = 8.0,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fleet", type=int, default=64,

@@ -42,4 +42,6 @@ def main(n_ticks: int = 1200) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     main()

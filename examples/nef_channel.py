@@ -5,7 +5,10 @@ MAC array, spike on fixed-point LIF, decode event-driven.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.nef import build_ensemble, run_channel
+
+enable_compilation_cache()
 
 ens = build_ensemble(n_neurons=512, dims=1, seed=0)
 t = np.arange(1200)

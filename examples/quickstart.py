@@ -9,11 +9,14 @@
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.dvfs import DVFSController
 from repro.core.hybrid import event_mac, event_mac_energy_j
 from repro.core.quant import quantize_params_linear, quantized_linear
 from repro.kernels.explog.ops import fx_exp_float
 from repro.kernels.lif.ops import lif_params_fx, lif_step
+
+enable_compilation_cache()
 
 rng = np.random.default_rng(0)
 

@@ -12,8 +12,11 @@ arrive, and narrows — checkpointing evicted sessions — as it drains.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core.dvfs import QueueDVFS
 from repro.serve.fleet import FleetEngine, PoissonTraffic, adaptive_scenario
+
+enable_compilation_cache()
 
 sc = adaptive_scenario(n_channels=1, n_neurons=64, learning_rate=1e-5)
 eng = FleetEngine(sc, round_ticks=64,

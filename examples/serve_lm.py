@@ -6,9 +6,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro import configs
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
+
+enable_compilation_cache()
 
 cfg = configs.get_arch("glm4-9b").smoke()
 params = T.init_params(cfg, jax.random.PRNGKey(0))

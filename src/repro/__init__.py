@@ -1,72 +1,1 @@
-"""Package root.
-
-Holds small compatibility shims so the codebase (written against newer jax
-APIs) runs on the pinned jax of this environment:
-
-* ``jax.sharding.AxisType`` + the ``axis_types=`` kwarg of ``jax.make_mesh``
-  (added after 0.4.37) — shimmed to a no-op enum / ignored kwarg.
-* ``jax.shard_map`` with ``check_vma=`` — aliased to
-  ``jax.experimental.shard_map.shard_map`` (``check_rep=``) when missing.
-
-The shims install at ``import repro`` so test subprocesses that only import
-a submodule get them too.
-"""
-from __future__ import annotations
-
-import enum
-import functools
-import inspect
-
-import jax
-
-
-def _install_jax_compat() -> None:
-    if not hasattr(jax.sharding, "AxisType"):
-        class AxisType(enum.Enum):
-            Auto = "auto"
-            Explicit = "explicit"
-            Manual = "manual"
-
-        jax.sharding.AxisType = AxisType
-
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _orig_make_mesh = jax.make_mesh
-
-        @functools.wraps(_orig_make_mesh)
-        def make_mesh(*args, axis_types=None, **kw):
-            return _orig_make_mesh(*args, **kw)
-
-        jax.make_mesh = make_mesh
-
-    # Compiled.cost_analysis() returns a single dict on newer jax but a
-    # one-element list of dicts on the pinned version; normalize to dict
-    # (repro.launch.dryrun / the dry-run tests index it directly).
-    try:
-        from jax import stages as _stages
-        _orig_ca = _stages.Compiled.cost_analysis
-
-        def _cost_analysis(self):
-            out = _orig_ca(self)
-            if isinstance(out, (list, tuple)):
-                return out[0] if out else {}
-            return out
-
-        if getattr(_orig_ca, "__name__", "") != "_cost_analysis":
-            _stages.Compiled.cost_analysis = _cost_analysis
-    except Exception:
-        pass
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @functools.wraps(_shard_map)
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-            if check_vma is not None:
-                kw.setdefault("check_rep", check_vma)
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-        jax.shard_map = shard_map
-
-
-_install_jax_compat()
+"""SpiNNaker 2 processing-element architecture as a JAX/Pallas system."""

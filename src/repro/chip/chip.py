@@ -16,10 +16,16 @@ per-link loads in packets AND in DNoC flits, so graded-payload
 accounting from ``NocSpec``.  The representation is auto-selected from
 the incidence shape — mesh size, density, per-link fan-in
 (``noc_mode="auto"``; force with "dense"/"sparse") — both paths agree
-bitwise on integer packet counts, and the incidence arrays are hoisted
-onto the device once, outside the per-tick closure.
+bitwise on integer packet counts.
 No per-source Python in the hot path, no per-workload branches in the
 engine.
+
+Everything the tick reads that is not its carry — the semantics'
+synaptic weights and drive tables, the NoC incidence or plan — is an
+ARGUMENT of the compiled program, never a constant inside it
+(``hoist_constants``): a 4096-PE synfire ring holds ~1 GB of weights,
+which as constants would make a ~1 GB executable.  Each (program,
+settings) pair traces and compiles once per ``ChipSim``.
 
 ``chip_power_table`` generalizes ``synfire_power_table`` from one PE
 average to the whole chip: per-PE table + chip totals + NoC power + the
@@ -28,7 +34,7 @@ SpiNNCer-style peak-link-load bottleneck check.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +48,32 @@ from repro.core.dvfs import DVFSController
 from repro.core.energy import PEEnergyModel
 
 
+def hoist_constants(fn: Callable, *example_args) -> tuple:
+    """Trace ``fn(*example_args)`` once and lift every array it closes
+    over into an explicit argument.
+
+    Returns ``(consts, pure)`` with ``pure(consts, *args) == fn(*args)``
+    for args shaped like ``example_args`` (arrays or
+    ``ShapeDtypeStruct``s).  ``consts`` holds the closed-over arrays in
+    trace order; ``pure`` closes over nothing but the traced program, so
+    a ``jax.jit`` of it takes the arrays as arguments instead of baking
+    them into the executable."""
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*example_args)
+    in_tree = jax.tree.structure(example_args)
+    out_tree = jax.tree.structure(out_shape)
+    jaxpr = closed.jaxpr
+
+    def pure(consts, *args):
+        leaves, tree = jax.tree.flatten(args)
+        if tree != in_tree:
+            raise ValueError(f"argument structure {tree} does not match "
+                             f"the traced {in_tree}")
+        return jax.tree.unflatten(
+            out_tree, jax.core.eval_jaxpr(jaxpr, consts, *leaves))
+
+    return tuple(closed.consts), pure
+
+
 @dataclass
 class ChipSim:
     """A compiled workload program on a full PE mesh (or, for a
@@ -52,8 +84,8 @@ class ChipSim:
     sparse vs dense by incidence density, "sparse"/"dense" force it (the
     two agree bitwise — forcing is for benchmarks and golden tests).
     ``link_load_impl`` overrides the program NoC's sparse accumulation
-    kernel (None defers to the NoC's own knob: "auto" -> the CPU column
-    plan; "pallas" -> the prefix-sum kernel, interpret-mode on CPU).
+    kernel (None defers to the NoC's own knob: "auto" -> the column
+    plan; "pallas" -> the prefix-sum kernel).
 
     ``exec_mode`` selects the execution mode: "dense" runs the per-PE
     work of every tick at full width; "event" runs the workload's
@@ -65,7 +97,7 @@ class ChipSim:
     compressed tick falls back to the dense formulas inside the scan
     whenever a tick's activity overflows the event buffer.
     ``event_impl`` picks the event NoC kernel (``repro.kernels.
-    event_gather``: "auto" delegates to the column plan on CPU;
+    event_gather``: "auto" delegates to the column plan;
     "gather"/"pallas" force the compacted-index variants).
     """
     program: ChipProgram
@@ -75,6 +107,12 @@ class ChipSim:
     link_load_impl: Optional[str] = None
     exec_mode: str = "auto"
     event_impl: Optional[str] = None
+    # (init, step, params) per stepper settings, and the jitted scans of
+    # ``run`` per (settings, n_ticks): each program compiles once
+    _steppers: dict = field(default_factory=dict, init=False, repr=False,
+                            compare=False)
+    _runs: dict = field(default_factory=dict, init=False, repr=False,
+                        compare=False)
 
     def __post_init__(self):
         if self.dvfs is None:
@@ -121,22 +159,38 @@ class ChipSim:
 
     def make_stepper(self, seed: int = 1, noc_mode: str | None = None,
                      link_load_impl: str | None = None,
-                     exec_mode: str | None = None):
-        """The batched-carry entry point: ``(init_state, step)`` where
-        ``step(state, t) -> (state, rec)`` is the engine's FULL per-tick
-        body — semantics tick, on-mesh learning, NoC accounting (sparse
-        or dense, tiered for boards) — exactly as ``run`` scans it.
+                     exec_mode: str | None = None) -> tuple:
+        """The batched-carry entry point: ``(init_state, step, params)``
+        where ``step(params, state, t) -> (state, rec)`` is the engine's
+        FULL per-tick body — semantics tick, on-mesh learning, NoC
+        accounting (sparse or dense, tiered for boards) — exactly as
+        ``run`` scans it.  ``params`` is the tuple of arrays the body
+        reads besides its carry (weights, drive tables, NoC plan); pass
+        it through ``jax.jit`` as an argument, not as a closure.
 
-        ``run`` itself is ``lax.scan(step, init, arange(n_ticks))``, so
-        anything that composes ``step`` differently — the serving tier's
-        ``jax.vmap`` over a fleet of independent instances
-        (``repro.serve.fleet``), chunked stepping with checkpoint /
-        restore of the carry between chunks, interleaved host I/O —
-        computes bit-identical per-tick records to a plain ``run`` of
-        the same program.  The carry returned by ``step`` is the full
-        engine state (workload state incl. the ``learn`` subtree), which
-        is what ``repro.ckpt`` snapshots for session save/restore.
+        ``run`` itself is ``lax.scan(partial(step, params), init,
+        arange(n_ticks))``, so anything that composes ``step``
+        differently — the serving tier's ``jax.vmap`` over a fleet of
+        independent instances (``repro.serve.fleet``), chunked stepping
+        with checkpoint / restore of the carry between chunks,
+        interleaved host I/O — computes bit-identical per-tick records
+        to a plain ``run`` of the same program.  The carry returned by
+        ``step`` is the full engine state (workload state incl. the
+        ``learn`` subtree), which is what ``repro.ckpt`` snapshots for
+        session save/restore.  Built once per settings and cached.
         """
+        key = (seed, noc_mode, link_load_impl, exec_mode)
+        if key not in self._steppers:
+            init, chip_tick = self._chip_tick(seed, noc_mode, link_load_impl,
+                                              exec_mode)
+            params, step = hoist_constants(
+                chip_tick, init, jax.ShapeDtypeStruct((), jnp.int32))
+            self._steppers[key] = (init, step, params)
+        return self._steppers[key]
+
+    def _chip_tick(self, seed, noc_mode, link_load_impl, exec_mode):
+        """``(init_state, chip_tick)``: the per-tick body as a closure
+        over this program's arrays (``make_stepper`` hoists them)."""
         prog = self.program
         event = self.use_event_mode(exec_mode)
         key = jax.random.PRNGKey(seed)
@@ -168,7 +222,6 @@ class ChipSim:
                 f"graph {prog.graph.name!r} has plastic projections but "
                 "its semantics' init_state does not carry a 'learn' "
                 "subtree; include repro.learn.init_learn_state(program)")
-        # incidence onto the device ONCE, outside the per-tick closure.
         # The kernel knob is validated even when the dense einsum wins
         # (a typo'd impl must error, not silently benchmark dense).
         impl = noc.resolve_link_load_impl(link_load_impl
@@ -227,7 +280,8 @@ class ChipSim:
             hit = (rec["link_load"] > 0).astype(jnp.float32)
             rec["touched_links"] = hit.sum(axis=-1)
             for tier, m in tier_masks.items():
-                rec[f"touched_links_{tier}"] = hit @ m
+                rec[f"touched_links_{tier}"] = jnp.matmul(
+                    hit, m, precision="highest")
             if tiered:
                 rec["load_xchip"] = (rec["link_load"] * xmask).sum(axis=-1)
                 rec["flits_xchip"] = (rec["link_flits"] * xmask).sum(axis=-1)
@@ -288,16 +342,20 @@ class ChipSim:
         output — the memory-bounded mode for long board-scale runs.
         """
         prog = self.program
-        init, chip_tick = self.make_stepper(seed=seed, noc_mode=noc_mode,
-                                            link_load_impl=link_load_impl,
-                                            exec_mode=exec_mode)
+        settings = (seed, noc_mode, link_load_impl, exec_mode)
+        init, step, params = self.make_stepper(*settings)
 
         if not probes:
             if not keep_records:
                 raise ValueError("keep_records=False without probes would "
                                  "record nothing; pass probes=...")
-            _, recs = jax.lax.scan(chip_tick, init, jnp.arange(n_ticks))
-            return recs
+            run = self._runs.get((settings, n_ticks))
+            if run is None:
+                def scan(params, init):
+                    return jax.lax.scan(lambda s, t: step(params, s, t),
+                                        init, jnp.arange(n_ticks))[1]
+                run = self._runs[(settings, n_ticks)] = jax.jit(scan)
+            return run(params, init)
 
         # telemetry: compile the probe accumulators into the scan carry
         # NEXT TO the workload state.  The probe step consumes the tick's
@@ -307,18 +365,21 @@ class ChipSim:
         from repro.obs.probes import make_probe_step, resolve_probes
         specs = resolve_probes(prog, probes)
         rec_shapes = jax.eval_shape(
-            chip_tick, init, jax.ShapeDtypeStruct((), jnp.int32))[1]
+            step, params, init, jax.ShapeDtypeStruct((), jnp.int32))[1]
         obs0, probe_step, finalize = make_probe_step(specs, rec_shapes,
                                                      n_ticks)
 
-        def probed_tick(carry, t):
-            state, obs = carry
-            state, rec = chip_tick(state, t)
-            obs = probe_step(obs, rec, t)
-            return (state, obs), (rec if keep_records else {})
+        @jax.jit
+        def probed_scan(params, init, obs0):
+            def probed_tick(carry, t):
+                state, obs = carry
+                state, rec = step(params, state, t)
+                obs = probe_step(obs, rec, t)
+                return (state, obs), (rec if keep_records else {})
+            return jax.lax.scan(probed_tick, (init, obs0),
+                                jnp.arange(n_ticks))
 
-        (_, obs), recs = jax.lax.scan(probed_tick, (init, obs0),
-                                      jnp.arange(n_ticks))
+        (_, obs), recs = probed_scan(params, init, obs0)
         recs = dict(recs) if keep_records else {}
         recs["probes"] = finalize(obs)
         return recs

@@ -38,10 +38,10 @@ from repro.kernels.link_load.ops import link_loads_cols, link_loads_csc
 
 SPIKE_PACKET_BITS = 64        # header-only DNoC spike packet (core/noc.py)
 
-# selectable sparse accumulation kernels: the CPU column plan (bucketed
-# gathers + prefix adds) or the Pallas sorted-segment prefix-sum kernel
-# (interpret mode on CPU, compiled on a real TPU target); "auto" resolves
-# to the column plan, the engine's measured-fastest CPU path
+# selectable sparse accumulation kernels: the column plan (bucketed
+# gathers + prefix adds) or the Pallas sorted-segment prefix-sum kernel;
+# "auto" resolves to the column plan (chosen on XLA:CPU timings; which
+# path wins on the TPU is not measured yet)
 LINK_LOAD_IMPLS = ("auto", "column_plan", "pallas")
 
 # incidence density above which the dense einsum beats the gather +
@@ -242,9 +242,8 @@ class NocAccounting:
     # -- sparse kernel selection ------------------------------------------
 
     def resolve_link_load_impl(self, impl: str | None = None) -> str:
-        """Resolve the sparse accumulation kernel ("auto" -> the CPU
-        column plan; "pallas" selects the sorted-segment prefix-sum
-        kernel, interpret-mode on CPU)."""
+        """Resolve the sparse accumulation kernel ("auto" -> the column
+        plan; "pallas" selects the sorted-segment prefix-sum kernel)."""
         impl = impl or getattr(self, "link_load_impl", "auto")
         if impl not in LINK_LOAD_IMPLS:
             raise ValueError(f"unknown link_load_impl {impl!r}; "
@@ -283,11 +282,11 @@ class NocAccounting:
 
     def resolve_event_impl(self, impl: str | None = None) -> str:
         """Resolve the event-mode accumulation kernel.  "auto" delegates
-        to the dense-weight column plan: it is already O(nnz), scatter-
-        free, and the measured-fastest CPU path (BENCH_pr3: 16.8 us at
-        4096 PEs) — the compacted-index kernels ("gather", "pallas";
-        ``repro.kernels.event_gather``) are the TPU-shaped variants whose
-        work is bounded by the event buffer instead of P."""
+        to the dense-weight column plan: it is already O(nnz) and
+        scatter-free (chosen on XLA:CPU timings) — the compacted-index
+        kernels ("gather", "pallas"; ``repro.kernels.event_gather``) are
+        the TPU-shaped variants whose work is bounded by the event buffer
+        instead of P."""
         impl = impl or getattr(self, "event_impl", "auto")
         if impl not in EVENT_GATHER_IMPLS:
             raise ValueError(f"unknown event_gather impl {impl!r}; "
@@ -327,16 +326,20 @@ class NocAccounting:
         activity telemetry both execution modes record identically
         (``repro.obs`` activity probes)."""
         hit = (link_loads > 0).astype(jnp.float32)
-        return {tier: hit @ jnp.asarray(mask)
+        return {tier: jnp.matmul(hit, jnp.asarray(mask), precision="highest")
                 for tier, mask in self.tier_masks().items()}
 
     # -- per-tick accounting (traced; dense or CSR) -----------------------
 
     def link_loads(self, packets, inc) -> jnp.ndarray:
         """packets: (..., n_sources) packet counts emitted per source this
-        tick; inc: (n_sources, n_links).  Returns (..., n_links) loads."""
+        tick; inc: (n_sources, n_links).  Returns (..., n_links) loads.
+
+        Every float32 contraction of the tick runs at HIGHEST precision:
+        at the default, a TPU multiplies in bfloat16, which holds
+        integers exactly only up to 256 — and flit counts exceed that."""
         return jnp.einsum("...p,pl->...l", packets.astype(jnp.float32),
-                          jnp.asarray(inc))
+                          jnp.asarray(inc), precision="highest")
 
     def link_loads_sparse(self, packets, buckets, inv_perm):
         """Sparse twin of ``link_loads``: bucketed column gathers +
@@ -373,7 +376,8 @@ class NocAccounting:
         """Per-link flit traffic: each source's packets weighted by its
         packet's flit count before hitting the incidence tensor."""
         w = packets.astype(jnp.float32) * self.packet_flits(payload_bits)
-        return jnp.einsum("...p,pl->...l", w, jnp.asarray(inc))
+        return jnp.einsum("...p,pl->...l", w, jnp.asarray(inc),
+                          precision="highest")
 
     def flit_loads_sparse(self, packets, buckets, inv_perm, payload_bits):
         """Sparse twin of ``flit_loads`` (same column plan as

@@ -256,9 +256,11 @@ class DnnPipelineSemantics:
             done = busy & (remaining == 0)
 
             done_f = done.astype(jnp.float32)
-            layer_done = (member @ done_f >= tiles).astype(jnp.float32)
+            layer_done = (jnp.matmul(member, done_f, precision="highest")
+                          >= tiles).astype(jnp.float32)
             packets = done_f * emit_mask               # activation bursts
-            buf = layer_done @ nxt                     # arrives next tick
+            buf = jnp.matmul(layer_done, nxt,          # arrives next tick
+                             precision="highest")
 
             macs = busy.astype(jnp.float32) * macs_tick
             cycles = busy.astype(jnp.float32) * cycles_tick
@@ -401,7 +403,7 @@ class HybridSemantics:
             spk_f = spk.astype(jnp.float32)
             n_spk = spk_f.sum().astype(jnp.int32)
             # event-based decode on the Arm core (only spikers contribute)
-            contrib = spk_f @ dec
+            contrib = jnp.matmul(spk_f, dec, precision="highest")
             # spikes/tick -> rate in Hz (decoders were solved against Hz
             # rates) — same discretization as core.nef.run_channel
             xhat = (alpha_syn * state["xhat"]
@@ -569,7 +571,7 @@ class HybridFarmSemantics:
 
             # MLP PEs consume LAST tick's spike vectors (1-tick transport)
             arr = state["spike_buf"]                          # (K, N)
-            h = arr @ w_eff                                   # (K, hidden)
+            h = jnp.matmul(arr, w_eff, precision="highest")  # (K, hidden)
             n_arr = arr.sum(axis=1)                           # (K,)
             mac_events = n_arr * hidden
             bits_in = self.bits_per_spike * n_arr

@@ -22,7 +22,7 @@ from repro.core.quant import quantize_per_axis
 from repro.kernels.mac_gemm.ops import mac_gemm
 
 
-def event_mac(values, active, wq, w_scale, *, capacity=None, interpret=True):
+def event_mac(values, active, wq, w_scale, *, capacity=None):
     """values: (T, K) float graded payloads; active: (T,) bool event mask;
     wq: (K, N) int8.  Returns (out (T, N) f32, n_dispatched).
 
@@ -36,7 +36,7 @@ def event_mac(values, active, wq, w_scale, *, capacity=None, interpret=True):
     src = jnp.concatenate([values, jnp.zeros((1, K), values.dtype)], axis=0)
     dispatched = src[idx]                                    # (C, K)
     xq, x_scale = quantize_per_axis(dispatched, axis=1)
-    acc = mac_gemm(xq, wq, interpret=interpret)
+    acc = mac_gemm(xq, wq)
     yq = acc.astype(jnp.float32) * x_scale[:, None] * w_scale[None, :]
     out = jnp.zeros((T + 1, wq.shape[1]), jnp.float32).at[idx].set(yq)
     return out[:T], jnp.sum(active.astype(jnp.int32))
@@ -53,7 +53,7 @@ def event_mac_tick(spikes, w_eff):
     """
     s = spikes.astype(jnp.float32)
     n_events = s.sum().astype(jnp.int32)
-    return s @ w_eff, n_events
+    return jnp.matmul(s, w_eff, precision="highest"), n_events
 
 
 def event_mac_energy_j(n_events, k, n, *, tops_per_w=None):

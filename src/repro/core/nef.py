@@ -100,8 +100,9 @@ def encode_drive(ens: Ensemble, x_seq, *, use_mac=True) -> jnp.ndarray:
         J = (acc.astype(jnp.float32) * x_scale[:, None]
              * jnp.asarray(ens.enc_scale)[None, :])
     else:
-        J = jnp.asarray(x_seq, jnp.float32) @ jnp.asarray(
-            ens.gains[:, None] * ens.encoders, jnp.float32).T
+        J = jnp.matmul(jnp.asarray(x_seq, jnp.float32), jnp.asarray(
+            ens.gains[:, None] * ens.encoders, jnp.float32).T,
+            precision="highest")
     J = J + jnp.asarray(ens.biases, jnp.float32)[None, :]
     alpha = ens.lif["alpha"] / FX_ONE
     return jnp.round(J * (1.0 - alpha) * FX_ONE).astype(jnp.int32)
@@ -127,7 +128,8 @@ def run_channel(ens: Ensemble, x_seq: np.ndarray, *, dt_ms=1.0,
         dfx = inp
         v, ref, spk = lif_step_ref(v, ref, dfx, **ens.lif)
         # event-based decode: only spiking neurons contribute (Arm core)
-        contrib = jnp.einsum("n,nd->d", spk.astype(jnp.float32), dec)
+        contrib = jnp.einsum("n,nd->d", spk.astype(jnp.float32), dec,
+                             precision="highest")
         # spikes/tick -> rate in Hz (decoders were solved against Hz rates)
         xhat = alpha_syn * xhat + (1 - alpha_syn) * contrib * (1000.0 / dt_ms)
         return (v, ref, xhat), (xhat, spk.sum(), spk)

@@ -17,7 +17,7 @@ def quantize_per_axis(x, axis: int, bits: int = 8):
     return q, jnp.squeeze(scale, axis=axis).astype(jnp.float32)
 
 
-def quantized_linear(x, wq, w_scale, *, interpret=True):
+def quantized_linear(x, wq, w_scale):
     """x: (M, K) float; wq: (K, N) int8 with per-col w_scale (N,).
 
     Activations are quantized per-row on the fly (the MAC array's graded
@@ -25,7 +25,7 @@ def quantized_linear(x, wq, w_scale, *, interpret=True):
     rescaled — the W8A8 serve path.
     """
     xq, x_scale = quantize_per_axis(x, axis=1)
-    acc = mac_gemm(xq, wq, interpret=interpret)
+    acc = mac_gemm(xq, wq)
     return acc.astype(jnp.float32) * x_scale[:, None] * w_scale[None, :]
 
 

@@ -15,7 +15,7 @@ The synfire chain (Fig. 16, Table II): 8 PEs in a ring; per PE one
 excitatory population (200) and one inhibitory population (50); exc of PE i
 projects to exc+inh of PE i+1 with 10 ms delay (fan-in 60); inh projects to
 exc of the same PE with 8 ms delay (fan-in 25); normally distributed noise
-current; a stimulus pulse packet kick-starts PE 0.
+current (``gauss_noise_fx``); a stimulus pulse packet kick-starts PE 0.
 
 Spike delay lines are stored bit-packed (one uint32 word per 32 neurons,
 ``pack_spikes``/``unpack_spikes``): the d×P×n int32 ring buffers were the
@@ -130,6 +130,31 @@ def shot_noise_lanes(seed32, t, n_kicks: int, n_lanes: int):
     c = jnp.asarray(t).astype(jnp.uint32) * jnp.uint32(n_kicks) \
         + jnp.arange(n_kicks, dtype=jnp.uint32)
     return (_fmix32(c ^ seed32) % jnp.uint32(n_lanes)).astype(jnp.int32)
+
+
+# ------------------------------------------------------------- Gaussian noise
+# standard deviation of a sum of four uniform 16-bit integers
+_SUM4_U16_STD = float(np.sqrt((2.0 ** 32 - 1) / 3))
+
+
+def gauss_noise_fx(key, t, shape: tuple, sigma_fx: int) -> jnp.ndarray:
+    """This tick's dense background current, int32 s16.15, approximately
+    normal with standard deviation ``sigma_fx``.
+
+    Each draw is a sum of four uniform 16-bit integers (Irwin-Hall,
+    n = 4: tails end at sqrt(12) sigma), centred, then scaled by ONE
+    float32 multiply and rounded.  Integer draws and one correctly
+    rounded multiply give the same bits on every backend.
+    ``jax.random.normal`` does not: its ``erf_inv`` differs between
+    XLA:CPU and the TPU in the last bits, which flips a rounding now and
+    then and, through the firing threshold, a spike.
+    """
+    bits = jax.random.bits(jax.random.fold_in(key, t), (2,) + tuple(shape),
+                           jnp.uint32)
+    halves = (bits & 0xFFFF) + (bits >> 16)
+    centred = (halves[0] + halves[1]).astype(jnp.int32) - 2 * 0xFFFF
+    scale = jnp.float32(sigma_fx / _SUM4_U16_STD)
+    return jnp.round(centred.astype(jnp.float32) * scale).astype(jnp.int32)
 
 
 @dataclass
@@ -262,9 +287,7 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
         if shot:
             lanes = shot_noise_lanes(seed32, t, net.kicks_per_tick, P_ * N)
             return i_syn.at[lanes // N, lanes % N].add(jnp.int32(net.kick_fx))
-        k = jax.random.fold_in(key, t)
-        noise = jax.random.normal(k, (P_, N))
-        return i_syn + jnp.round(noise * net.noise_sigma_fx).astype(jnp.int32)
+        return i_syn + gauss_noise_fx(key, t, (P_, N), net.noise_sigma_fx)
 
     def add_stim(i_syn, t):
         stim = jnp.where(
@@ -438,10 +461,8 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
         i_syn = jax.lax.cond((n_src <= cap_eff) & (n_chunks <= kc),
                              compressed, dense_path, (arr_exc, arr_inh))
         if not shot:
-            k = jax.random.fold_in(key, t)
-            noise = jax.random.normal(k, (P_, N))
-            i_syn = i_syn + jnp.round(
-                noise * net.noise_sigma_fx).astype(jnp.int32)
+            i_syn = i_syn + gauss_noise_fx(key, t, (P_, N),
+                                           net.noise_sigma_fx)
 
         # 3. dense LIF + dense energy pricing: fused elementwise passes
         #    over regular arrays — cheaper than compacting them on CPU

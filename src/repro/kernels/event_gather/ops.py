@@ -7,13 +7,12 @@ Layouts of the same computation (see ``repro.kernels.event_gather.ref``):
   work, independent of P; the jnp reference path of the compacted-index
   formulation.
 * ``impl="pallas"`` — same gather stage, accumulation through the
-  one-hot lane kernel (``event_gather.onehot_link_accum_pallas``,
-  interpret mode on CPU, compiled on a real TPU target).
+  one-hot lane kernel (``event_gather.onehot_link_accum_pallas``;
+  ``repro.kernels.platform`` compiles it on TPU, interprets it elsewhere).
 * ``impl="auto"`` — resolved by the ENGINE (``repro.chip.mesh_noc.
-  NocAccounting.event_plan``): on CPU it delegates to the dense-weight
-  column plan, which is already O(nnz) with no scatter and measured
-  fastest there; the compacted-index impls here are the TPU-shaped
-  variants and the oracle-tested reference semantics.
+  NocAccounting.event_plan``) to the dense-weight column plan, which is
+  O(nnz) with no scatter; the compacted-index impls here are the
+  TPU-shaped variants and the oracle-tested reference semantics.
 
 All impls sum the same exact integer-valued terms per link (quiescent
 lanes contribute exact 0.0), so they agree bitwise with each other and
@@ -65,12 +64,10 @@ def event_link_loads_gather(idx, weights, rows_padded, *, n_links: int):
     return jax.ops.segment_sum(w, ids, num_segments=n_links + 1)[:n_links]
 
 
-@functools.partial(jax.jit, static_argnames=("n_links", "interpret"))
-def event_link_loads_pallas(idx, weights, rows_padded, *, n_links: int,
-                            interpret=True):
+@functools.partial(jax.jit, static_argnames=("n_links",))
+def event_link_loads_pallas(idx, weights, rows_padded, *, n_links: int):
     ids, w = gather_entries(idx, weights, rows_padded)
-    return onehot_link_accum_pallas(ids, w, n_links=n_links,
-                                    interpret=interpret)
+    return onehot_link_accum_pallas(ids, w, n_links=n_links)
 
 
 def event_link_loads(idx, weights, rows_padded, *, n_links: int,

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
 from repro.kernels.explog.ref import FRAC, FX_ONE, LN2, LOG_TABLE, _MAX_EXP_ARG
 
 BLOCK_ROWS = 256
@@ -69,22 +70,21 @@ def _fx_log_kernel(x_ref, o_ref):
     o_ref[...] = jnp.where(bad, jnp.int32(-(2**30)), acc)
 
 
-def _elementwise_call(kernel, x2d, interpret=True):
+def _elementwise_call(kernel, x2d):
     R, C = x2d.shape
     assert C == LANES and R % BLOCK_ROWS == 0
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(R // BLOCK_ROWS,),
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.int32),
-        interpret=interpret,
     )(x2d)
 
 
-def fx_exp_pallas(x, interpret=True):
-    return _elementwise_call(_fx_exp_kernel, x, interpret)
+def fx_exp_pallas(x):
+    return _elementwise_call(_fx_exp_kernel, x)
 
 
-def fx_log_pallas(x, interpret=True):
-    return _elementwise_call(_fx_log_kernel, x, interpret)
+def fx_log_pallas(x):
+    return _elementwise_call(_fx_log_kernel, x)

@@ -2,13 +2,12 @@
 
 Both wrappers take an ``impl`` knob, mirroring the ``link_load_impl``
 convention of ``repro.chip.mesh_noc``: "pallas" selects the Pallas kernel
-(interpret-mode on CPU hosts, compiled on a real TPU target), "ref" the
-pure-jnp bit-exact oracle, and "auto" resolves to the measured-fastest
-CPU path — the reference, since interpret-mode Pallas pays a large
-per-call overhead.  The two implementations are BIT-IDENTICAL (enforced
-by tests/test_kernels_explog.py), so the knob only moves wall time; the
-engine's plasticity trace decay (``repro.learn``) selects "auto" so
-learning ticks stay fast on interpret-mode hosts.
+(compiled on TPU, interpreted elsewhere — ``repro.kernels.platform``),
+"ref" the pure-jnp bit-exact oracle, and "auto" resolves to the
+reference, which XLA fuses into the surrounding tick.  The two
+implementations are BIT-IDENTICAL (enforced by
+tests/test_kernels_explog.py), so the knob only moves wall time; the
+engine's plasticity trace decay (``repro.learn``) selects "auto".
 """
 from __future__ import annotations
 
@@ -26,7 +25,7 @@ EXPLOG_IMPLS = ("auto", "ref", "pallas")
 
 
 def resolve_explog_impl(impl: str) -> str:
-    """"auto" -> the reference path (fastest on interpret-mode hosts)."""
+    """"auto" -> the reference path (fused by XLA into its caller)."""
     if impl not in EXPLOG_IMPLS:
         raise ValueError(f"unknown explog impl {impl!r}; expected one of "
                          f"{EXPLOG_IMPLS}")
@@ -43,23 +42,23 @@ def _shape_to_blocks(x):
     return flat.reshape(-1, LANES), n
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
-def fx_exp(x, impl="auto", interpret=True):
+@functools.partial(jax.jit, static_argnames=("impl",))
+def fx_exp(x, impl="auto"):
     """x: int32 s16.15 any shape -> exp(x) int32 s16.15."""
     if resolve_explog_impl(impl) == "ref":
         return fx_exp_ref(jnp.asarray(x))
     x2d, n = _shape_to_blocks(x)
-    out = fx_exp_pallas(x2d, interpret=interpret)
+    out = fx_exp_pallas(x2d)
     return out.reshape(-1)[:n].reshape(x.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
-def fx_log(x, impl="auto", interpret=True):
+@functools.partial(jax.jit, static_argnames=("impl",))
+def fx_log(x, impl="auto"):
     """x: int32 s16.15 any shape, > 0 -> ln(x) int32 s16.15."""
     if resolve_explog_impl(impl) == "ref":
         return fx_log_ref(jnp.asarray(x))
     x2d, n = _shape_to_blocks(x)
-    out = fx_log_pallas(x2d, interpret=interpret)
+    out = fx_log_pallas(x2d)
     return out.reshape(-1)[:n].reshape(x.shape)
 
 
@@ -71,9 +70,9 @@ def from_fx(x_fx):
     return x_fx.astype(jnp.float32) / FX_ONE
 
 
-def fx_exp_float(x_float, interpret=True):
-    return from_fx(fx_exp(to_fx(x_float), interpret=interpret))
+def fx_exp_float(x_float):
+    return from_fx(fx_exp(to_fx(x_float)))
 
 
-def fx_log_float(x_float, interpret=True):
-    return from_fx(fx_log(to_fx(x_float), interpret=interpret))
+def fx_log_float(x_float):
+    return from_fx(fx_log(to_fx(x_float)))

@@ -13,8 +13,8 @@ attention: accumulators stay put, operands stream.
 Causal masking is applied per block; fully-masked future blocks are
 ZEROED (their contribution) but still iterated — Pallas grids are dense.
 On a real deployment `num_stages`/block sizes would be tuned per chip;
-here blocks default to MXU-aligned 128s and correctness is validated in
-interpret mode against ref.py.
+here blocks default to MXU-aligned 128s and correctness is validated
+against ref.py.
 """
 from __future__ import annotations
 
@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.platform import pallas_call
 
 NEG = -1e30
 
@@ -67,8 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                     jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
 
 
-def flash_attention_pallas(q, k, v, *, bq=128, bk=128, causal=True,
-                           interpret=True):
+def flash_attention_pallas(q, k, v, *, bq=128, bk=128, causal=True):
     """q, k, v: (BH, S, D) — batch*heads flattened.  Returns (BH, S, D)."""
     BH, S, D = q.shape
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
@@ -76,7 +77,7 @@ def flash_attention_pallas(q, k, v, *, bq=128, bk=128, causal=True,
     scale = 1.0 / np.sqrt(D)
     kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, nk=nk,
                                scale=scale, causal=causal)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(BH, nq, nk),
         in_specs=[
@@ -91,5 +92,4 @@ def flash_attention_pallas(q, k, v, *, bq=128, bk=128, causal=True,
             pltpu.VMEM((bq,), jnp.float32),       # l
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
-        interpret=interpret,
     )(q, k, v)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
 from repro.kernels.lif.ref import FRAC, fx_mul
 
 BLOCK_ROWS = 256
@@ -35,7 +36,7 @@ def _lif_kernel(v_ref, ref_ref, isyn_ref, vo_ref, refo_ref, sp_ref, *,
 
 
 def lif_step_pallas(v, ref_ct, i_syn, *, alpha, v_th, v_reset, ref_ticks,
-                    v_min=None, interpret=True):
+                    v_min=None):
     """All inputs (R, 128) int32; R multiple of BLOCK_ROWS."""
     R, C = v.shape
     assert C == LANES and R % BLOCK_ROWS == 0
@@ -44,11 +45,10 @@ def lif_step_pallas(v, ref_ct, i_syn, *, alpha, v_th, v_reset, ref_ticks,
                                v_min=v_min)
     bs = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
     sds = jax.ShapeDtypeStruct((R, C), jnp.int32)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(R // BLOCK_ROWS,),
         in_specs=[bs, bs, bs],
         out_specs=(bs, bs, bs),
         out_shape=(sds, sds, sds),
-        interpret=interpret,
     )(v, ref_ct, i_syn)

@@ -36,15 +36,15 @@ def _pad2d(x):
 
 @functools.partial(jax.jit,
                    static_argnames=("alpha", "v_th", "v_reset", "ref_ticks",
-                                    "v_min", "interpret"))
+                                    "v_min"))
 def lif_step(v, ref_ct, i_syn, *, alpha, v_th, v_reset, ref_ticks,
-             v_min=None, interpret=True):
+             v_min=None):
     """v, ref_ct, i_syn: (N,) int32.  Returns (v', ref', spikes) each (N,)."""
     v2, n = _pad2d(v)
     r2, _ = _pad2d(ref_ct)
     i2, _ = _pad2d(i_syn)
     vo, ro, so = lif_step_pallas(v2, r2, i2, alpha=alpha, v_th=v_th,
                                  v_reset=v_reset, ref_ticks=ref_ticks,
-                                 v_min=v_min, interpret=interpret)
+                                 v_min=v_min)
     unpad = lambda x: x.reshape(-1)[:n]
     return unpad(vo), unpad(ro), unpad(so)

@@ -50,9 +50,8 @@ def link_loads_csr(weights, link_ids, src_of_entry, *, n_links: int):
     return link_loads_ref(weights, link_ids, src_of_entry, n_links)
 
 
-@functools.partial(jax.jit, static_argnames=("n_links", "interpret"))
-def link_loads_csc(weights, src_sorted, link_ptr, *, n_links: int,
-                   interpret=True):
+@functools.partial(jax.jit, static_argnames=("n_links",))
+def link_loads_csc(weights, src_sorted, link_ptr, *, n_links: int):
     """weights: (P,) per-source counts; src_sorted/link_ptr: the
     ``SparseIncidence.csc`` layout.  Returns (n_links,) link loads."""
     w = jnp.take(weights.astype(jnp.float32), src_sorted)     # (nnz,)
@@ -60,7 +59,6 @@ def link_loads_csc(weights, src_sorted, link_ptr, *, n_links: int,
     pad = per if w.shape[0] == 0 else (-w.shape[0]) % per
     if pad:
         w = jnp.pad(w, (0, pad))
-    csum = flat_prefix_sum_pallas(w.reshape(-1, LANES),
-                                  interpret=interpret).reshape(-1)
+    csum = flat_prefix_sum_pallas(w.reshape(-1, LANES)).reshape(-1)
     s = jnp.concatenate([jnp.zeros(1, jnp.float32), csum])    # exclusive
     return s[link_ptr[1:]] - s[link_ptr[:-1]]

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 
 def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, bh, wo, sh, sw, kh, kw):
     """x_ref: (1, Hp, Wp, Cin) padded input (whole image resident in VMEM);
@@ -49,8 +51,7 @@ def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, bh, wo, sh, sw, kh, kw):
     o_ref[0] = acc_ref[...].reshape(bh, wo, -1)
 
 
-def mac_conv2d_pallas(x, w, *, stride=(1, 1), bh=8, bcout=128,
-                      interpret=True):
+def mac_conv2d_pallas(x, w, *, stride=(1, 1), bh=8, bcout=128):
     """x: (B, Hp, Wp, Cin) int8/uint8 PRE-PADDED; w: (KH, KW, Cin, Cout).
 
     Returns (B, Ho, Wo, Cout) int32 with Ho = (Hp-KH)//sh + 1.
@@ -63,7 +64,7 @@ def mac_conv2d_pallas(x, w, *, stride=(1, 1), bh=8, bcout=128,
     Wo = (Wp - KW) // sw + 1
     assert Ho % bh == 0 and Cout % bcout == 0, (Ho, bh, Cout, bcout)
     grid = (B, Ho // bh, Cout // bcout)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_conv_kernel, bh=bh, wo=Wo, sh=sh, sw=sw,
                           kh=KH, kw=KW),
         grid=grid,
@@ -74,5 +75,4 @@ def mac_conv2d_pallas(x, w, *, stride=(1, 1), bh=8, bcout=128,
         out_specs=pl.BlockSpec((1, bh, Wo, bcout), lambda b, i, j: (b, i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Cout), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bh * Wo, bcout), jnp.int32)],
-        interpret=interpret,
     )(x, w)

@@ -10,10 +10,8 @@ from repro.kernels.mac_conv.mac_conv import mac_conv2d_pallas
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("stride", "padding", "bh", "bcout",
-                                    "interpret"))
-def mac_conv2d(x, w, *, stride=(1, 1), padding="VALID", bh=8, bcout=128,
-               interpret=True):
+                   static_argnames=("stride", "padding", "bh", "bcout"))
+def mac_conv2d(x, w, *, stride=(1, 1), padding="VALID", bh=8, bcout=128):
     """x: (B,H,W,Cin) int8/uint8; w: (KH,KW,Cin,Cout) -> (B,Ho,Wo,Cout) int32."""
     B, H, W, Cin = x.shape
     KH, KW, _, Cout = w.shape
@@ -37,6 +35,5 @@ def mac_conv2d(x, w, *, stride=(1, 1), padding="VALID", bh=8, bcout=128,
     pad_c = (-Cout) % bc_eff
     if pad_c:
         w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, pad_c)))
-    out = mac_conv2d_pallas(x, w, stride=stride, bh=bh_eff, bcout=bc_eff,
-                            interpret=interpret)
+    out = mac_conv2d_pallas(x, w, stride=stride, bh=bh_eff, bcout=bc_eff)
     return out[:, :Ho, :Wo, :Cout]

@@ -13,7 +13,9 @@ TPU adaptation of the SpiNNaker2 16x4 8-bit output-stationary MAC array
 
 Scaling up: the paper's 4x16 array becomes a 128x128 MXU tile; blocks are
 (BM, BK) x (BK, BN) with 128-multiples so every dot hits the systolic array
-natively.  Validated on CPU with interpret=True against ref.py.
+natively.  The 8-bit operands go into the dot as they are, accumulating in
+int32 (``preferred_element_type``): Mosaic has no int32 x int32 matmul.
+Bitwise equal to ref.py.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 128
 
 
@@ -33,10 +37,9 @@ def _mac_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)          # (BM, BK) int8 -> int32
-    b = b_ref[...].astype(jnp.int32)          # (BK, BN)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():
@@ -44,7 +47,7 @@ def _mac_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
 
 
 def mac_gemm_pallas(a: jax.Array, b: jax.Array, *, bm=DEFAULT_BM,
-                    bn=DEFAULT_BN, bk=DEFAULT_BK, interpret=True) -> jax.Array:
+                    bn=DEFAULT_BN, bk=DEFAULT_BK) -> jax.Array:
     """a: (M, K) int8/uint8; b: (K, N) int8/uint8 -> (M, N) int32.
 
     Shapes must be multiples of the block sizes (ops.mac_gemm pads).
@@ -54,7 +57,7 @@ def mac_gemm_pallas(a: jax.Array, b: jax.Array, *, bm=DEFAULT_BM,
     assert K == K2, (a.shape, b.shape)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
     nk = K // bk
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_mac_gemm_kernel, nk=nk),
         grid=(M // bm, N // bn, nk),
         in_specs=[
@@ -64,5 +67,4 @@ def mac_gemm_pallas(a: jax.Array, b: jax.Array, *, bm=DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
     )(a, b)

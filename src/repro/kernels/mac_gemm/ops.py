@@ -19,21 +19,20 @@ def _pad_to(x, m0, m1):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def mac_gemm(a, b, *, bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
-             interpret=True):
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
+def mac_gemm(a, b, *, bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK):
     """int8/uint8 GEMM with int32 accumulation; pads to block multiples."""
     M, K = a.shape
     _, N = b.shape
     ap = _pad_to(a, bm, bk)
     bp = _pad_to(b, bk, bn)
-    out = mac_gemm_pallas(ap, bp, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    out = mac_gemm_pallas(ap, bp, bm=bm, bn=bn, bk=bk)
     return out[:M, :N]
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def mac_gemm_dequant(a, b, a_scale, b_scale, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
-                     bk=DEFAULT_BK, interpret=True):
+                     bk=DEFAULT_BK):
     """W8A8 path: int32 accumulate then per-row/col rescale to f32."""
-    acc = mac_gemm(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    acc = mac_gemm(a, b, bm=bm, bn=bn, bk=bk)
     return acc.astype(jnp.float32) * a_scale[:, None] * b_scale[None, :]

@@ -324,7 +324,8 @@ class StdpPairSemantics:
             arr = state["buf"]                           # arrived (1-tick)
             w = state["learn"]["pre->post"]["w"]         # (n_pre, n_post)
             w_f = w.astype(jnp.float32) / FX_ONE
-            i_syn = jnp.round((arr @ w_f) * gain * FX_ONE).astype(jnp.int32)
+            i_syn = jnp.round(jnp.matmul(arr, w_f, precision="highest")
+                              * gain * FX_ONE).astype(jnp.int32)
             v, ref, post_spk = lif_step_ref(state["v"], state["ref"],
                                             i_syn, **self.lif)
 

@@ -111,14 +111,16 @@ class FleetEngine:
         # only saves the work the compressed branch itself skips; the
         # single-instance speedup story lives in ChipSim.run.
         self.sim = ChipSim(self.program, exec_mode=exec_mode)
-        self._template, self._tick = self.sim.make_stepper(seed=seed)
+        # the program's weights/tables ride every round as an argument
+        self._template, self._tick, self._params = self.sim.make_stepper(
+            seed=seed)
 
         self.capacity = int(capacity or max(self.dvfs.batch_levels))
         self.levels = sorted({min(int(l), self.capacity)
                               for l in self.dvfs.batch_levels})
 
         self._rec_sd = jax.eval_shape(
-            self._tick, self._template,
+            self._tick, self._params, self._template,
             jax.ShapeDtypeStruct((), jnp.int32))[1]
         self.energy_keys = tuple(k for k in ENERGY_KEYS
                                  if k in self._rec_sd)
@@ -170,7 +172,7 @@ class FleetEngine:
         if fn is not None:
             return fn
         Tc, out_keys, e_keys = self.Tc, self.output_keys, self.energy_keys
-        vtick = jax.vmap(self._tick, in_axes=(0, 0))
+        vtick = jax.vmap(self._tick, in_axes=(None, 0, 0))
         if self.probe_specs:
             _, pstep, _ = make_batched_probe_step(
                 self.probe_specs, self._rec_sd, self.probe_ticks, w)
@@ -181,10 +183,10 @@ class FleetEngine:
         else:
             dinit, dstep = {}, None
 
-        def run_round(carry, t0s):
+        def run_round(params, carry, t0s):
             def body(c, i):
                 ts = t0s + i                       # per-instance local tick
-                st, rec = vtick(c["st"], ts)
+                st, rec = vtick(params, c["st"], ts)
                 obs = pstep(c["obs"], rec, ts) if pstep else c["obs"]
                 met = dstep(c["met"], rec) if dstep else c["met"]
                 out = {k: rec[k] for k in out_keys}
@@ -395,8 +397,8 @@ class FleetEngine:
                               + [0] * (w - n_active), jnp.int32)
 
             wall0 = time.perf_counter()
-            self._carry, outs, es, met = self._round_fn(w)(self._carry,
-                                                           t0s)
+            self._carry, outs, es, met = self._round_fn(w)(
+                self._params, self._carry, t0s)
             es = jax.block_until_ready(es)
             round_s = time.perf_counter() - wall0
             tick_lat_s.append(round_s / self.Tc)

@@ -311,7 +311,7 @@ class ServedKwsSemantics:
             bits_out = self.bits_per_spike * n_spk
 
             arr = state["spike_buf"]                          # (K, N)
-            h = arr @ w_eff                                   # (K, hidden)
+            h = jnp.matmul(arr, w_eff, precision="highest")  # (K, hidden)
             n_arr = arr.sum(axis=1)
             mac_events = n_arr * hidden
             bits_in = self.bits_per_spike * n_arr

@@ -146,10 +146,11 @@ def test_engine_scan_carry_roundtrip_bitwise(tmp_path):
     from repro.learn.adaptive import adaptive_control_graph
 
     g = adaptive_control_graph(n_channels=2, n_neurons=24, n_ticks=64)
-    init, tick = ChipSim(compile_graph(g)).make_stepper()
+    init, step, params = ChipSim(compile_graph(g)).make_stepper()
 
     def run(st, t0, n):
-        return jax.lax.scan(tick, st, t0 + jnp.arange(n))
+        return jax.lax.scan(lambda s, t: step(params, s, t), st,
+                            t0 + jnp.arange(n))
     runj = jax.jit(run, static_argnums=2)
 
     ref_st, ref_recs = runj(init, 0, 32)

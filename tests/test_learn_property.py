@@ -107,11 +107,16 @@ def test_pes_zero_error_is_exact_fixed_point(n, d, lr, seed):
     rule = PES(learning_rate=lr)
     out = pes_step(dec, act, jnp.zeros(d), rule, n)
     assert np.array_equal(np.asarray(out), np.asarray(dec))
-    # ...and a nonzero error moves the decoders against its sign
+    # ...and a nonzero error moves the decoders against its sign, where
+    # the float32 step lr/n * a is at least one ulp of the decoder (a
+    # smaller step rounds away: d - step == d)
     err = jnp.ones(d)
     out2 = np.asarray(pes_step(dec, act, err, rule, n))
     moved = np.asarray(dec) - out2
-    assert (moved[np.asarray(act) > 0] > 0).all()
+    step = np.float32(lr / n) * np.asarray(act)[:, None]
+    resolved = step >= np.spacing(np.abs(np.asarray(dec)))
+    assert (moved >= 0).all()
+    assert (moved[resolved] > 0).all()
 
 
 @given(xs=st.lists(st.floats(-10.0, 10.0), min_size=1, max_size=64))

@@ -34,11 +34,17 @@ from repro.obs.report import diff_benches, main as report_main
 from repro.obs.trace import main as trace_main
 
 
-def _assert_same_records(a: dict, b: dict, keys=None):
+def _assert_same_records(a: dict, b: dict, keys=None, float_ulps=0):
+    """Records equal bitwise; with ``float_ulps`` > 0, floating-point
+    records may differ by that many units in the last place."""
     for k in (keys or a):
         if k == "probes":
             continue
-        assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
+        x, y = np.asarray(a[k]), np.asarray(b[k])
+        if float_ulps and np.issubdtype(x.dtype, np.floating):
+            np.testing.assert_array_max_ulp(x, y, maxulp=float_ulps)
+        else:
+            assert np.array_equal(x, y), k
 
 
 # -------------------------------------------------------------------------
@@ -60,11 +66,15 @@ def test_probes_off_golden_synfire_vs_seed_engine():
 
 def test_probed_run_leaves_records_bitwise_identical():
     """Probes only read the tick's records — compiling them into the
-    carry must not perturb a single bit of the per-tick records."""
+    carry must not perturb a single bit of the integer records.  A
+    float32 energy may move by one ulp: the probed program is a
+    different XLA program, which may fuse (and contract to FMA) the
+    Eq. (1) multiply-adds differently; a probe writing state would move
+    the integer records too."""
     sim = ChipSim(compile_graph(synfire_graph(8)))
     bare = sim.run(300)
     probed = sim.run(300, probes=default_probes(sim.program))
-    _assert_same_records(bare, probed, keys=bare)
+    _assert_same_records(bare, probed, keys=bare, float_ulps=1)
     assert set(probed["probes"]) >= {"link_flits_peak", "pe_pl_mean",
                                      "pe_packets_sum", "e_noc_sum"}
 

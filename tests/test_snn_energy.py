@@ -6,7 +6,8 @@ import pytest
 from repro.configs import paper
 from repro.core.dvfs import DVFSController
 from repro.core.energy import PEEnergyModel
-from repro.core.snn import build_synfire, simulate_synfire, synfire_power_table
+from repro.core.snn import (build_synfire, gauss_noise_fx, simulate_synfire,
+                            synfire_power_table)
 pytest.importorskip("hypothesis")
 from hypothesis import given, strategies as st
 
@@ -49,6 +50,25 @@ def test_table_iii_reductions(sim):
     # absolute anchors from Table I: only-PL3 baseline == P_BL,3
     assert abs(tab["pl3"]["baseline"] - 66.44) < 0.1
     assert abs(tab["dvfs"]["baseline"] - 24.3) < 3.0       # paper: 24.3 mW
+
+
+def test_gauss_noise_is_integer_exact_and_normal_shaped():
+    """The background current is integer arithmetic plus one rounded
+    float32 multiply — NumPy recomputes it bit for bit from the same
+    random bits — with the requested standard deviation."""
+    import jax
+    key, t, sigma = jax.random.PRNGKey(3), 7, 9830
+    noise = np.asarray(gauss_noise_fx(key, t, (500, 500), sigma))
+    bits = np.asarray(jax.random.bits(jax.random.fold_in(key, t),
+                                      (2, 500, 500), np.uint32))
+    s = ((bits & 0xFFFF) + (bits >> 16)).sum(axis=0).astype(np.int64)
+    scale = np.float32(sigma / np.sqrt((2.0 ** 32 - 1) / 3))
+    expect = np.round((s - 2 * 0xFFFF).astype(np.float32) * scale)
+    assert noise.dtype == np.int32
+    np.testing.assert_array_equal(noise, expect.astype(np.int32))
+    assert abs(noise.mean()) < 0.01 * sigma
+    assert abs(noise.std() / sigma - 1) < 0.01
+    assert np.abs(noise).max() <= np.sqrt(12) * sigma + 1
 
 
 def test_energy_model_matches_hand_calc():

@@ -1,0 +1,118 @@
+"""Ahead-of-time compiles for TPU v5e of the main path's Pallas kernels
+and of one engine step, at the shapes the chip runs.
+
+Nothing here runs on a chip: the TPU compiler, installed with JAX,
+compiles for a described ``v5e:2x2`` topology.  What it refuses here
+(tiling, unsupported ops, scalar stores to VMEM) it would refuse on the
+chip.  The topology is described inside a fixture, never at import, so
+every test worker collects the same tests and only the worker that runs
+this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.chip.chip import ChipSim
+from repro.chip.compile import compile as compile_graph
+from repro.chip.workloads import synfire_graph
+from repro.kernels.event_gather.event_gather import onehot_link_accum_pallas
+from repro.kernels.explog.explog import fx_exp_pallas, fx_log_pallas
+from repro.kernels.lif.ops import lif_step
+from repro.kernels.link_load.link_load import flat_prefix_sum_pallas
+from repro.kernels.mac_gemm.ops import mac_gemm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e chip, with the persistent compilation cache off:
+    an entry written for a described chip cannot be read back without
+    one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (kernel, argument shapes/dtypes): the shapes of the 4096-PE synfire
+# ring (nnz 1054 -> 16 rows; 3968 links; the event NoC gathers every
+# source's padded tree row, 4096 x 31 entries; 250 x 4096 neurons) and
+# of the 4x12 hybrid-farm board (nnz 10752 -> 88 rows; a 256-tick x
+# 1-dim drive encoded onto 32 neurons)
+KERNELS = {
+    "prefix_sum_synfire4096": (
+        flat_prefix_sum_pallas, [((16, 128), jnp.float32)]),
+    "prefix_sum_board4x12": (
+        flat_prefix_sum_pallas, [((88, 128), jnp.float32)]),
+    "onehot_accum_synfire4096": (
+        lambda ids, w: onehot_link_accum_pallas(ids, w, n_links=3968),
+        [((4096 * 31,), jnp.int32), ((4096 * 31,), jnp.float32)]),
+    "fx_exp": (fx_exp_pallas, [((256, 128), jnp.int32)]),
+    "fx_log": (fx_log_pallas, [((256, 128), jnp.int32)]),
+    "lif_step_synfire4096": (
+        lambda v, r, i: lif_step(v, r, i, alpha=29_650, v_th=32_768,
+                                 v_reset=0, ref_ticks=2, v_min=-32_768),
+        [((250 * 4096,), jnp.int32)] * 3),
+    "mac_gemm_board_drive": (
+        mac_gemm, [((256, 1), jnp.int8), ((1, 32), jnp.int8)]),
+    "mac_gemm_fleet_segment": (
+        mac_gemm, [((64, 1), jnp.int8), ((1, 64), jnp.int8)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = KERNELS[name]
+    args = [_sds(s, d, one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def synfire256():
+    return compile_graph(synfire_graph(256))
+
+
+@pytest.mark.parametrize("settings", [
+    {},                                                  # auto: event mode
+    {"exec_mode": "dense", "noc_mode": "sparse",
+     "link_load_impl": "pallas"},
+    {"exec_mode": "event", "noc_mode": "sparse"},
+])
+def test_synfire_engine_step_takes_weights_as_arguments(settings, one_chip,
+                                                        synfire256):
+    """One tick of a 256-PE synfire ring compiles for v5e, and its
+    ~64 MB of synaptic slabs are arguments of the program, not code."""
+    net = synfire256.graph.semantics.net
+    weight_bytes = net.w_ff.nbytes + net.w_inh.nbytes
+    sim = ChipSim(synfire256, event_impl="pallas" if settings else None)
+    init, step, params = sim.make_stepper(**settings)
+    as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    compiled = jax.jit(step).lower(
+        jax.tree.map(as_sds, params), jax.tree.map(as_sds, init),
+        _sds((), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= weight_bytes
+    assert mem.generated_code_size_in_bytes < weight_bytes // 16
+    if settings:
+        assert "tpu_custom_call" in compiled.as_text()
