@@ -107,8 +107,10 @@ class ChipSim:
     link_load_impl: Optional[str] = None
     exec_mode: str = "auto"
     event_impl: Optional[str] = None
-    # (init, step, params) per stepper settings, and the jitted scans of
-    # ``run`` per (settings, n_ticks): each program compiles once
+    # (init, step, params) per stepper settings, and the compiled scans
+    # of ``run`` per (settings, n_ticks[, probes, keep_records]), each
+    # with its extra arguments and probe finalizer: each program
+    # compiles once
     _steppers: dict = field(default_factory=dict, init=False, repr=False,
                             compare=False)
     _runs: dict = field(default_factory=dict, init=False, repr=False,
@@ -181,10 +183,11 @@ class ChipSim:
         """
         key = (seed, noc_mode, link_load_impl, exec_mode)
         if key not in self._steppers:
-            init, chip_tick = self._chip_tick(seed, noc_mode, link_load_impl,
-                                              exec_mode)
-            params, step = hoist_constants(
-                chip_tick, init, jax.ShapeDtypeStruct((), jnp.int32))
+            with jax.profiler.TraceAnnotation("chip.build"):
+                init, chip_tick = self._chip_tick(seed, noc_mode,
+                                                  link_load_impl, exec_mode)
+                params, step = hoist_constants(
+                    chip_tick, init, jax.ShapeDtypeStruct((), jnp.int32))
             self._steppers[key] = (init, step, params)
         return self._steppers[key]
 
@@ -254,12 +257,21 @@ class ChipSim:
             xmask = jnp.asarray(noc.xlink_mask, jnp.float32)
             tree_links_x = jnp.asarray(prog.tree_links_x, jnp.float32)
 
+        @jax.named_scope("chip_tick")
         def chip_tick(state, t):
-            state, rec = tick(state, t)
+            with jax.named_scope("semantics"):
+                state, rec = tick(state, t)
             if learn is not None:
-                lstate, lrec = learn(state["learn"], rec)
+                with jax.named_scope("learn"):
+                    lstate, lrec = learn(state["learn"], rec)
                 state = {**state, "learn": lstate}
                 rec.update(lrec)
+            with jax.named_scope("noc"):
+                return state, noc_records(rec)
+
+        def noc_records(rec):
+            """The tick's record with the engine's NoC accounting and
+            activity telemetry added."""
             packets = rec["packets"].astype(jnp.float32)    # (P,)
             pb = rec.get("payload_bits", static_pb)
             if sparse and event:
@@ -287,7 +299,7 @@ class ChipSim:
                 rec["flits_xchip"] = (rec["link_flits"] * xmask).sum(axis=-1)
                 rec["e_noc_xchip"] = noc.xchip_energy_j(packets,
                                                         tree_links_x, pb)
-            return state, rec
+            return rec
 
         return init, chip_tick
 
@@ -340,49 +352,92 @@ class ChipSim:
         bare engine's.  ``keep_records=False`` (probed runs only) drops
         the full (T, ...) per-tick records and returns just the probe
         output — the memory-bounded mode for long board-scale runs.
-        """
-        prog = self.program
-        settings = (seed, noc_mode, link_load_impl, exec_mode)
-        init, step, params = self.make_stepper(*settings)
 
+        Each (settings, n_ticks[, probes, keep_records]) compiles once,
+        ahead of time, with the stage of every instruction recorded
+        (``repro.obs.scopes``).  Under a profiler trace a call shows as
+        the host span ``chip.run`` (args: ``n_ticks``, the resolved
+        ``exec_mode`` and ``noc_mode``, ``cached``) holding
+        ``chip.build`` (a new stepper), ``chip.compile`` (a new program)
+        and ``chip.dispatch``.
+        """
+        settings = (seed, noc_mode, link_load_impl, exec_mode)
         if not probes:
             if not keep_records:
                 raise ValueError("keep_records=False without probes would "
                                  "record nothing; pass probes=...")
-            run = self._runs.get((settings, n_ticks))
-            if run is None:
-                def scan(params, init):
-                    return jax.lax.scan(lambda s, t: step(params, s, t),
-                                        init, jnp.arange(n_ticks))[1]
-                run = self._runs[(settings, n_ticks)] = jax.jit(scan)
-            return run(params, init)
-
-        # telemetry: compile the probe accumulators into the scan carry
-        # NEXT TO the workload state.  The probe step consumes the tick's
-        # records and never feeds back into state, so probed runs stay
-        # bit-identical to bare runs — only the carry grows.  (import
-        # here: repro.obs reaches back into repro.chip for helpers)
-        from repro.obs.probes import make_probe_step, resolve_probes
-        specs = resolve_probes(prog, probes)
-        rec_shapes = jax.eval_shape(
-            step, params, init, jax.ShapeDtypeStruct((), jnp.int32))[1]
-        obs0, probe_step, finalize = make_probe_step(specs, rec_shapes,
-                                                     n_ticks)
-
-        @jax.jit
-        def probed_scan(params, init, obs0):
-            def probed_tick(carry, t):
-                state, obs = carry
-                state, rec = step(params, state, t)
-                obs = probe_step(obs, rec, t)
-                return (state, obs), (rec if keep_records else {})
-            return jax.lax.scan(probed_tick, (init, obs0),
-                                jnp.arange(n_ticks))
-
-        (_, obs), recs = probed_scan(params, init, obs0)
+            key = (settings, n_ticks)
+        else:
+            from repro.obs.probes import resolve_probes
+            specs = resolve_probes(self.program, probes)
+            key = (settings, n_ticks, specs, keep_records)
+        with jax.profiler.TraceAnnotation(
+                "chip.run", n_ticks=n_ticks, cached=key in self._runs,
+                exec_mode="event" if self.use_event_mode(exec_mode)
+                else "dense",
+                noc_mode="sparse" if self.use_sparse_noc(noc_mode)
+                else "dense"):
+            init, step, params = self.make_stepper(*settings)
+            if key not in self._runs:
+                with jax.profiler.TraceAnnotation("chip.compile"):
+                    if probes:
+                        entry = _probed_scan(step, params, init, n_ticks,
+                                             specs, keep_records)
+                    else:
+                        entry = _scan(step, params, init, n_ticks)
+                self._runs[key] = entry
+            run, extra, finalize = self._runs[key]
+            with jax.profiler.TraceAnnotation("chip.dispatch"):
+                out = run(params, init, *extra)
+        if not probes:
+            return out
+        (_, obs), recs = out
         recs = dict(recs) if keep_records else {}
         recs["probes"] = finalize(obs)
         return recs
+
+
+def _scan(step, params, init, n_ticks: int) -> tuple:
+    """``run``'s compiled scan, its extra arguments and finalizer."""
+    def scan(params, init):
+        return jax.lax.scan(lambda s, t: step(params, s, t),
+                            init, jnp.arange(n_ticks))[1]
+    return _compile(scan, params, init), (), None
+
+
+def _probed_scan(step, params, init, n_ticks: int, specs: tuple,
+                 keep_records: bool) -> tuple:
+    """``run``'s compiled scan with probes, its extra arguments (the
+    probe accumulators' initial state) and the probes' finalizer."""
+    # telemetry: compile the probe accumulators into the scan carry
+    # NEXT TO the workload state.  The probe step consumes the tick's
+    # records and never feeds back into state, so probed runs stay
+    # bit-identical to bare runs — only the carry grows.  (import here:
+    # repro.obs reaches back into repro.chip for helpers)
+    from repro.obs.probes import make_probe_step
+    rec_shapes = jax.eval_shape(
+        step, params, init, jax.ShapeDtypeStruct((), jnp.int32))[1]
+    obs0, probe_step, finalize = make_probe_step(specs, rec_shapes, n_ticks)
+
+    def probed_scan(params, init, obs0):
+        def probed_tick(carry, t):
+            state, obs = carry
+            state, rec = step(params, state, t)
+            obs = probe_step(obs, rec, t)
+            return (state, obs), (rec if keep_records else {})
+        return jax.lax.scan(probed_tick, (init, obs0), jnp.arange(n_ticks))
+
+    return _compile(probed_scan, params, init, obs0), (obs0,), finalize
+
+
+def _compile(fn: Callable, *args):
+    """``jax.jit(fn)`` compiled ahead of time for ``args``, with the
+    stage of every instruction recorded (``repro.obs.scopes``): a device
+    op in a profiler trace names only its instruction."""
+    from repro.obs.scopes import record
+    compiled = jax.jit(fn).lower(*args).compile()
+    record(compiled.as_text())
+    return compiled
 
 
 def chip_power_table(sim: ChipSim, recs: dict,

@@ -272,6 +272,12 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
     bitwise identical to ``event=False`` by construction: integer
     accumulation is reassociation-exact, and a skipped PE's synaptic
     input is exactly the zero row the dense einsum computes for it.
+
+    Each stage of either tick runs under a ``jax.named_scope``: ``fifo``,
+    ``synapse`` (in the event tick also the compaction and the cond,
+    whose branches are ``compressed`` and ``dense_fallback``),
+    ``background``, ``neuron`` and ``route`` (``repro.obs.scopes``).
+    The names reach only the compiled program's op metadata.
     """
     sp = net.params
     P_, NE, NI = sp.n_pes, sp.n_exc, sp.n_inh
@@ -337,28 +343,35 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
             e_pl3["baseline"], e_pl3["neuron"], e_pl3["synapse"]])
 
     def dense_tick(state, t):
-        # 1. drain FIFOs (spikes that arrive this tick)
-        we = state["exc_buf"][t % d_exc]               # (P, WE) packed
-        wi = state["inh_buf"][t % d_inh]               # (P, WI) packed
-        arr_exc = unpack_spikes(we, NE)                # (P, NE) from prev PE
-        arr_inh = unpack_spikes(wi, NI)                # (P, NI) same PE
-        n_fifo = popcount_words(we) + popcount_words(wi)
+        with jax.named_scope("fifo"):
+            # 1. drain FIFOs (spikes that arrive this tick)
+            we = state["exc_buf"][t % d_exc]           # (P, WE) packed
+            wi = state["inh_buf"][t % d_inh]           # (P, WI) packed
+            arr_exc = unpack_spikes(we, NE)            # (P, NE) prev PE
+            arr_inh = unpack_spikes(wi, NI)            # (P, NI) same PE
+            n_fifo = popcount_words(we) + popcount_words(wi)
 
-        # 2. DVFS: FIFO occupancy picks the PL before processing
-        pl = dvfs.select_pl(n_fifo)                    # (P,)
+            # 2. DVFS: FIFO occupancy picks the PL before processing
+            pl = dvfs.select_pl(n_fifo)                # (P,)
 
-        # 3. synaptic accumulation (event-driven integer MAC)
-        i_ff = jnp.einsum("pe,pen->pn", arr_exc, net.w_ff)
-        i_in = jnp.einsum("pi,pie->pe", arr_inh, net.w_inh)
-        i_syn = add_stim(add_noise(i_ff.at[:, :NE].add(i_in), t), t)
+        with jax.named_scope("synapse"):
+            # 3. synaptic accumulation (event-driven integer MAC)
+            i_ff = jnp.einsum("pe,pen->pn", arr_exc, net.w_ff)
+            i_in = jnp.einsum("pi,pie->pe", arr_inh, net.w_inh)
+            i_syn = i_ff.at[:, :NE].add(i_in)
+        with jax.named_scope("background"):
+            i_syn = add_stim(add_noise(i_syn, t), t)
 
-        # 4. LIF update (bit-identical to the Pallas kernel) + accounting
-        v, ref, spk = lif_step_ref(state["v"], state["ref"], i_syn,
-                                   **net.lif)
-        syn_events = (jnp.einsum("pe,pe->p", arr_exc, net.deg_ff)
-                      + jnp.einsum("pi,pi->p", arr_inh, net.deg_inh))
-        return finish(state, t, pl, n_fifo, syn_events, v, ref, spk,
-                      energy_stack(pl, syn_events), {})
+        with jax.named_scope("neuron"):
+            # 4. LIF update (bit-identical to the Pallas kernel), accounting
+            v, ref, spk = lif_step_ref(state["v"], state["ref"], i_syn,
+                                       **net.lif)
+            syn_events = (jnp.einsum("pe,pe->p", arr_exc, net.deg_ff)
+                          + jnp.einsum("pi,pi->p", arr_inh, net.deg_inh))
+            energy_rows = energy_stack(pl, syn_events)
+        with jax.named_scope("route"):
+            return finish(state, t, pl, n_fifo, syn_events, v, ref, spk,
+                          energy_rows, {})
 
     # two-level compaction geometry (event tick only)
     nc = -(-P_ // EVENT_CHUNK)                         # chunks of 64 PEs
@@ -386,90 +399,101 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
         return idx, c_any.sum()
 
     def event_tick(state, t):
-        # 1. drain FIFOs — popcount on the packed words gives n_fifo and
-        #    the arrival mask without unpacking
-        we = state["exc_buf"][t % d_exc]
-        wi = state["inh_buf"][t % d_inh]
-        n_fifo = popcount_words(we) + popcount_words(wi)
-        pl = dvfs.select_pl(n_fifo)
-        arr_exc = unpack_spikes(we, NE)
-        arr_inh = unpack_spikes(wi, NI)
+        with jax.named_scope("fifo"):
+            # 1. drain FIFOs — popcount on the packed words gives n_fifo
+            #    and the arrival mask without unpacking
+            we = state["exc_buf"][t % d_exc]
+            wi = state["inh_buf"][t % d_inh]
+            n_fifo = popcount_words(we) + popcount_words(wi)
+            pl = dvfs.select_pl(n_fifo)
+            arr_exc = unpack_spikes(we, NE)
+            arr_inh = unpack_spikes(wi, NI)
 
-        # syn_events: fused dense elementwise — integer-exact match of
-        # the dense einsum, and cheaper than gathering deg tables
-        syn_events = ((arr_exc * net.deg_ff).sum(axis=1)
-                      + (arr_inh * net.deg_inh).sum(axis=1))
+        with jax.named_scope("neuron"):
+            # syn_events: fused dense elementwise — integer-exact match of
+            # the dense einsum, and cheaper than gathering deg tables
+            syn_events = ((arr_exc * net.deg_ff).sum(axis=1)
+                          + (arr_inh * net.deg_inh).sum(axis=1))
 
-        # 2. the input set: every PE receiving anything this tick —
-        #    spike arrivals, shot-noise kicks, the stimulus.  (A dense
-        #    Gaussian background is NOT input-sparse; it is added
-        #    densely after the cond, identically in both branches.)
-        src = n_fifo > 0
-        if shot:
-            lanes = shot_noise_lanes(seed32, t, net.kicks_per_tick, P_ * N)
-            src = src.at[lanes // N].set(True)
-        if net.stim_ticks > 0:
-            src = src.at[0].set(src[0] | (t < net.stim_ticks))
-        n_src = src.sum()
-        idx, n_chunks = compact(src)                   # (cap_eff,)
-        safe = jnp.minimum(idx, P_ - 1)
-        valid = idx < P_
-
-        def compressed(ops):
-            arr_e, arr_i = ops
-            m = valid[:, None]
-            ae = arr_e[safe] * m                       # (cap_eff, NE)
-            ai = arr_i[safe] * m                       # (cap_eff, NI)
-            # gather only the touched weight slabs
-            i_k = jnp.einsum("ke,ken->kn", ae, net.w_ff[safe])
-            i_k = i_k.at[:, :NE].add(
-                jnp.einsum("ki,kie->ke", ai, net.w_inh[safe]))
+        with jax.named_scope("synapse"):
+            # 2. the input set: every PE receiving anything this tick —
+            #    spike arrivals, shot-noise kicks, the stimulus.  (A dense
+            #    Gaussian background is NOT input-sparse; it is added
+            #    densely after the cond, identically in both branches.)
+            src = n_fifo > 0
             if shot:
-                # every kicked PE is in the input set, so searchsorted
-                # finds its exact lane in the sorted index buffer
-                kpos = jnp.searchsorted(idx, lanes // N)
-                i_k = i_k.at[jnp.minimum(kpos, cap_eff - 1),
-                             lanes % N].add(jnp.int32(net.kick_fx))
+                lanes = shot_noise_lanes(seed32, t, net.kicks_per_tick,
+                                         P_ * N)
+                src = src.at[lanes // N].set(True)
             if net.stim_ticks > 0:
-                # PE 0 is forced into the set while stimulated, so it
-                # owns lane 0 of the sorted buffer exactly when present
-                hit0 = (t < net.stim_ticks) & (idx[0] == 0)
-                i_k = i_k.at[0, :NE].add(
-                    jnp.where(hit0, jnp.int32(net.stim_current_fx),
-                              jnp.int32(0)))
-            # ONE bounded scatter back to the dense current (sentinel
-            # lanes drop); skipped PEs keep the exact zero rows the
-            # dense einsum would compute for them
-            return jnp.zeros((P_, N), jnp.int32).at[idx].set(i_k,
-                                                             mode="drop")
+                src = src.at[0].set(src[0] | (t < net.stim_ticks))
+            n_src = src.sum()
+            idx, n_chunks = compact(src)               # (cap_eff,)
+            safe = jnp.minimum(idx, P_ - 1)
+            valid = idx < P_
 
-        def dense_path(ops):
-            arr_e, arr_i = ops
-            i_ff = jnp.einsum("pe,pen->pn", arr_e, net.w_ff)
-            i_syn = i_ff.at[:, :NE].add(
-                jnp.einsum("pi,pie->pe", arr_i, net.w_inh))
-            if shot:
-                i_syn = i_syn.at[lanes // N, lanes % N].add(
-                    jnp.int32(net.kick_fx))
-            if net.stim_ticks > 0:
-                i_syn = i_syn.at[0, :NE].add(
-                    jnp.where(t < net.stim_ticks,
-                              jnp.int32(net.stim_current_fx),
-                              jnp.int32(0)))
-            return i_syn
+            @jax.named_scope("compressed")
+            def compressed(ops):
+                arr_e, arr_i = ops
+                m = valid[:, None]
+                ae = arr_e[safe] * m                   # (cap_eff, NE)
+                ai = arr_i[safe] * m                   # (cap_eff, NI)
+                # gather only the touched weight slabs
+                i_k = jnp.einsum("ke,ken->kn", ae, net.w_ff[safe])
+                i_k = i_k.at[:, :NE].add(
+                    jnp.einsum("ki,kie->ke", ai, net.w_inh[safe]))
+                if shot:
+                    # every kicked PE is in the input set, so
+                    # searchsorted finds its exact lane in the sorted
+                    # index buffer
+                    kpos = jnp.searchsorted(idx, lanes // N)
+                    i_k = i_k.at[jnp.minimum(kpos, cap_eff - 1),
+                                 lanes % N].add(jnp.int32(net.kick_fx))
+                if net.stim_ticks > 0:
+                    # PE 0 is forced into the set while stimulated, so it
+                    # owns lane 0 of the sorted buffer exactly when present
+                    hit0 = (t < net.stim_ticks) & (idx[0] == 0)
+                    i_k = i_k.at[0, :NE].add(
+                        jnp.where(hit0, jnp.int32(net.stim_current_fx),
+                                  jnp.int32(0)))
+                # ONE bounded scatter back to the dense current (sentinel
+                # lanes drop); skipped PEs keep the exact zero rows the
+                # dense einsum would compute for them
+                return jnp.zeros((P_, N), jnp.int32).at[idx].set(
+                    i_k, mode="drop")
 
-        i_syn = jax.lax.cond((n_src <= cap_eff) & (n_chunks <= kc),
-                             compressed, dense_path, (arr_exc, arr_inh))
+            @jax.named_scope("dense_fallback")
+            def dense_path(ops):
+                arr_e, arr_i = ops
+                i_ff = jnp.einsum("pe,pen->pn", arr_e, net.w_ff)
+                i_syn = i_ff.at[:, :NE].add(
+                    jnp.einsum("pi,pie->pe", arr_i, net.w_inh))
+                if shot:
+                    i_syn = i_syn.at[lanes // N, lanes % N].add(
+                        jnp.int32(net.kick_fx))
+                if net.stim_ticks > 0:
+                    i_syn = i_syn.at[0, :NE].add(
+                        jnp.where(t < net.stim_ticks,
+                                  jnp.int32(net.stim_current_fx),
+                                  jnp.int32(0)))
+                return i_syn
+
+            i_syn = jax.lax.cond((n_src <= cap_eff) & (n_chunks <= kc),
+                                 compressed, dense_path, (arr_exc, arr_inh))
         if not shot:
-            i_syn = i_syn + gauss_noise_fx(key, t, (P_, N),
-                                           net.noise_sigma_fx)
+            with jax.named_scope("background"):
+                i_syn = i_syn + gauss_noise_fx(key, t, (P_, N),
+                                               net.noise_sigma_fx)
 
-        # 3. dense LIF + dense energy pricing: fused elementwise passes
-        #    over regular arrays — cheaper than compacting them on CPU
-        v, ref, spk = lif_step_ref(state["v"], state["ref"], i_syn,
-                                   **net.lif)
-        return finish(state, t, pl, n_fifo, syn_events, v, ref, spk,
-                      energy_stack(pl, syn_events), {})
+        with jax.named_scope("neuron"):
+            # 3. dense LIF + dense energy pricing: fused elementwise passes
+            #    over regular arrays — cheaper than compacting them on CPU
+            v, ref, spk = lif_step_ref(state["v"], state["ref"], i_syn,
+                                       **net.lif)
+            energy_rows = energy_stack(pl, syn_events)
+        with jax.named_scope("route"):
+            return finish(state, t, pl, n_fifo, syn_events, v, ref, spk,
+                          energy_rows, {})
 
     return event_tick if event else dense_tick
 

@@ -3,13 +3,15 @@
 The real SpiNNaker 2 PE drives DVFS from live activity counters — per-PE
 performance monitoring is an architectural feature, not an afterthought
 (Mayr et al., arXiv:1911.02385).  This package is the simulator's
-equivalent, in four layers:
+equivalent, in these layers:
 
 * ``probes``   — declarative ``ProbeSpec``s compiled INTO the engine's
   ``lax.scan`` carry: sampling strides + windowed reductions (peak /
   mean / EMA / last) so board-scale runs record without host round-trips
   or per-tick memory blow-up.  Zero probes trace bitwise-identically to
   the bare engine.
+* ``scopes``   — the named stages of the engine's tick as the compiled
+  program placed them, for splitting a device profile by stage.
 * ``trace``    — export of recorded timelines to Chrome/Perfetto
   trace-event JSON (per-PE compute/DVFS tracks, per-NoC-tier flit
   counters, learn updates), viewable at https://ui.perfetto.dev.
