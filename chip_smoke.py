@@ -6,8 +6,8 @@ Everything runs in this one process (a chip belongs to one process),
 through the library's own entry points, and checks its own results:
 
 1. **Synfire ring** — ``synfire_graph(4096)`` with the paper's Table II
-   per-core counts (250 neurons per PE, ~1 GB of int32 synaptic slabs on
-   the device) -> ``compile`` -> ``ChipSim``, 256 ticks three times:
+   per-core counts (250 neurons per PE, ~0.5 GB of int16 synaptic slabs
+   on the device) -> ``compile`` -> ``ChipSim``, 256 ticks three times:
    ``exec_mode="dense"``, ``"auto"`` (event mode at this size), and
    ``"auto"`` with both Pallas NoC kernels forced.  Integer-valued
    records are bitwise equal across the three, the wave is still alive

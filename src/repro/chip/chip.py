@@ -183,11 +183,17 @@ class ChipSim:
         """
         key = (seed, noc_mode, link_load_impl, exec_mode)
         if key not in self._steppers:
-            with jax.profiler.TraceAnnotation("chip.build"):
+            with jax.profiler.TraceAnnotation("chip.build") as span:
                 init, chip_tick = self._chip_tick(seed, noc_mode,
                                                   link_load_impl, exec_mode)
                 params, step = hoist_constants(
                     chip_tick, init, jax.ShapeDtypeStruct((), jnp.int32))
+                # workload semantics may name settings of their own
+                # (e.g. the synfire ring's synaptic slab width)
+                build_args = getattr(self.program.graph.semantics,
+                                     "build_args", None)
+                if build_args:
+                    span.set_metadata(**build_args())
             self._steppers[key] = (init, step, params)
         return self._steppers[key]
 
@@ -358,7 +364,8 @@ class ChipSim:
         (``repro.obs.scopes``).  Under a profiler trace a call shows as
         the host span ``chip.run`` (args: ``n_ticks``, the resolved
         ``exec_mode`` and ``noc_mode``, ``cached``) holding
-        ``chip.build`` (a new stepper), ``chip.compile`` (a new program)
+        ``chip.build`` (a new stepper; args: the semantics'
+        ``build_args()``, if it has one), ``chip.compile`` (a new program)
         and ``chip.dispatch``.
         """
         settings = (seed, noc_mode, link_load_impl, exec_mode)

@@ -74,6 +74,11 @@ class SynfireSemantics:
         sp = self.net.params
         return DVFSController(sp.l_th1, sp.l_th2)
 
+    def build_args(self) -> dict:
+        """Args of ``ChipSim``'s ``chip.build`` host span: the storage
+        width of the synaptic slabs (``core.snn.slab_dtype``)."""
+        return {"w_dtype": str(self.net.w_ff.dtype)}
+
 
 def synfire_graph(n_pes: int = 8, seed: int = 0,
                   sp: paper.SynfireParams = paper.SYNFIRE,

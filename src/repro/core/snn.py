@@ -160,8 +160,10 @@ def gauss_noise_fx(key, t, shape: tuple, sigma_fx: int) -> jnp.ndarray:
 @dataclass
 class SynfireNet:
     params: paper.SynfireParams
-    w_ff: jnp.ndarray        # (P, 200, 250) int32 s16.15: prev-exc -> [exc|inh]
-    w_inh: jnp.ndarray       # (P, 50, 200) int32 s16.15 (negative)
+    # synaptic slabs, s16.15 in int16 when every weight fits, else int32
+    # (``slab_dtype``); the einsums accumulate in int32 either way
+    w_ff: jnp.ndarray        # (P, 200, 250) s16.15: prev-exc -> [exc|inh]
+    w_inh: jnp.ndarray       # (P, 50, 200) s16.15 (negative)
     deg_ff: jnp.ndarray      # (P, 200) int32: out-degree of each prev-exc source
     deg_inh: jnp.ndarray     # (P, 50) int32
     lif: dict
@@ -171,6 +173,17 @@ class SynfireNet:
     noise_model: str = "gauss"   # "gauss" (dense threefry) | "shot" (kicks)
     kicks_per_tick: int = 0
     kick_fx: int = 0
+
+
+def slab_dtype(*slabs: np.ndarray) -> type:
+    """The narrowest exact storage of s16.15 synaptic slabs: ``int16``
+    when every weight fits it (the synfire weights do, |w| <= 9830),
+    else ``int32``.  The synaptic matrix-vector products stream every
+    slab from memory on every tick, so the storage width is their cost;
+    they accumulate in int32, so the width never changes a sum."""
+    lo, hi = np.iinfo(np.int16).min, np.iinfo(np.int16).max
+    fits = all(s.min() >= lo and s.max() <= hi for s in slabs)
+    return np.int16 if fits else np.int32
 
 
 def build_synfire(seed: int = 0, *, w_exc: float = 0.075, w_inh: float = -0.30,
@@ -218,10 +231,13 @@ def build_synfire(seed: int = 0, *, w_exc: float = 0.075, w_inh: float = -0.30,
     # and the synfire wave dies before completing one ring traversal.
     lif = lif_params_fx(tau_ms=tau_ms, v_th=v_th, v_reset=0.0,
                         ref_ticks=ref_ticks, v_min=v_min)
+    w_ff_fx = np.round(w_ff * FX_ONE).astype(np.int32)
+    w_inh_fx = np.round(w_inh_m * FX_ONE).astype(np.int32)
+    w_dtype = slab_dtype(w_ff_fx, w_inh_fx)
     return SynfireNet(
         params=sp,
-        w_ff=jnp.asarray(np.round(w_ff * FX_ONE), jnp.int32),
-        w_inh=jnp.asarray(np.round(w_inh_m * FX_ONE), jnp.int32),
+        w_ff=jnp.asarray(w_ff_fx.astype(w_dtype)),
+        w_inh=jnp.asarray(w_inh_fx.astype(w_dtype)),
         deg_ff=jnp.asarray((w_ff != 0).sum(axis=2), jnp.int32),
         deg_inh=jnp.asarray((w_inh_m != 0).sum(axis=2), jnp.int32),
         lif=lif,
@@ -356,8 +372,10 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
 
         with jax.named_scope("synapse"):
             # 3. synaptic accumulation (event-driven integer MAC)
-            i_ff = jnp.einsum("pe,pen->pn", arr_exc, net.w_ff)
-            i_in = jnp.einsum("pi,pie->pe", arr_inh, net.w_inh)
+            i_ff = jnp.einsum("pe,pen->pn", arr_exc, net.w_ff,
+                              preferred_element_type=jnp.int32)
+            i_in = jnp.einsum("pi,pie->pe", arr_inh, net.w_inh,
+                              preferred_element_type=jnp.int32)
             i_syn = i_ff.at[:, :NE].add(i_in)
         with jax.named_scope("background"):
             i_syn = add_stim(add_noise(i_syn, t), t)
@@ -439,9 +457,11 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
                 ae = arr_e[safe] * m                   # (cap_eff, NE)
                 ai = arr_i[safe] * m                   # (cap_eff, NI)
                 # gather only the touched weight slabs
-                i_k = jnp.einsum("ke,ken->kn", ae, net.w_ff[safe])
+                i_k = jnp.einsum("ke,ken->kn", ae, net.w_ff[safe],
+                                 preferred_element_type=jnp.int32)
                 i_k = i_k.at[:, :NE].add(
-                    jnp.einsum("ki,kie->ke", ai, net.w_inh[safe]))
+                    jnp.einsum("ki,kie->ke", ai, net.w_inh[safe],
+                               preferred_element_type=jnp.int32))
                 if shot:
                     # every kicked PE is in the input set, so
                     # searchsorted finds its exact lane in the sorted
@@ -465,9 +485,11 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
             @jax.named_scope("dense_fallback")
             def dense_path(ops):
                 arr_e, arr_i = ops
-                i_ff = jnp.einsum("pe,pen->pn", arr_e, net.w_ff)
+                i_ff = jnp.einsum("pe,pen->pn", arr_e, net.w_ff,
+                                  preferred_element_type=jnp.int32)
                 i_syn = i_ff.at[:, :NE].add(
-                    jnp.einsum("pi,pie->pe", arr_i, net.w_inh))
+                    jnp.einsum("pi,pie->pe", arr_i, net.w_inh,
+                               preferred_element_type=jnp.int32))
                 if shot:
                     i_syn = i_syn.at[lanes // N, lanes % N].add(
                         jnp.int32(net.kick_fx))

@@ -9,9 +9,11 @@ every test worker collects the same tests and only the worker that runs
 this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -102,9 +104,10 @@ def synfire256():
 def test_synfire_engine_step_takes_weights_as_arguments(settings, one_chip,
                                                         synfire256):
     """One tick of a 256-PE synfire ring compiles for v5e, and its
-    ~64 MB of synaptic slabs are arguments of the program, not code."""
+    ~31 MB of synaptic slabs are arguments of the program, not code."""
     net = synfire256.graph.semantics.net
     weight_bytes = net.w_ff.nbytes + net.w_inh.nbytes
+    n_weights = net.w_ff.size + net.w_inh.size
     sim = ChipSim(synfire256, event_impl="pallas" if settings else None)
     init, step, params = sim.make_stepper(**settings)
     as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
@@ -113,6 +116,53 @@ def test_synfire_engine_step_takes_weights_as_arguments(settings, one_chip,
         _sds((), jnp.int32, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= weight_bytes
-    assert mem.generated_code_size_in_bytes < weight_bytes // 16
+    # under a quarter byte of code per weight, whatever the slab width
+    assert mem.generated_code_size_in_bytes < n_weights // 4
     if settings:
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def _materialised(hlo: str) -> set:
+    """Shapes (``s32[256,200,250]``) of the arrays an optimised module
+    writes to memory: results of instructions outside fused
+    computations, whose intermediates live in registers only."""
+    fused = set(re.findall(r" fusion\(.*calls=(%[\w.\-]+)", hlo))
+    shapes, inside = set(), False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and line.endswith("{"):
+            inside = head.group(1) in fused
+        elif not inside:
+            shapes.update(re.findall(r"^\s+(?:ROOT )?%\S+ = (\w+\[[\d,]*\])",
+                                     line))
+    return shapes
+
+
+@pytest.mark.parametrize("exec_mode", ["dense", "event"])
+def test_synfire_scan_streams_int16_slabs(exec_mode, one_chip, synfire256):
+    """The engine's scan over a 256-PE ring reads the int16 slabs it is
+    given: the int16 -> int32 conversion stays fused into the
+    multiply-reduce, never an int32 copy of a slab (a copy hoisted out
+    of the tick loop would be read instead on every tick)."""
+    net = synfire256.graph.semantics.net
+    P, NE, NI, N = (net.params.n_pes, net.params.n_exc, net.params.n_inh,
+                    net.params.neurons_per_core)
+    slabs = {(P, NE, N), (P, NI, NE)}
+    assert {(w.shape, w.dtype) for w in (net.w_ff, net.w_inh)} == {
+        (s, np.dtype(np.int16)) for s in slabs}
+    int32_bytes = 4 * (net.w_ff.size + net.w_inh.size)
+    init, step, params = ChipSim(synfire256).make_stepper(
+        exec_mode=exec_mode)
+    assert {a.shape for a in params if a.dtype == jnp.int16} == slabs
+
+    def scan(params, init):
+        return jax.lax.scan(lambda s, t: step(params, s, t), init,
+                            jnp.arange(4))[1]
+    as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    compiled = jax.jit(scan).lower(jax.tree.map(as_sds, params),
+                                   jax.tree.map(as_sds, init)).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < int32_bytes
+    written = _materialised(compiled.as_text())
+    assert f"s16[{P},{NE},{N}]" in written           # the parameter
+    for shape in slabs:
+        assert f"s32[{','.join(map(str, shape))}]" not in written
