@@ -16,8 +16,10 @@ through the library's own entry points, and checks its own results:
    1e-5 relative, see ``compare``).  The compiled tick takes the
    weights as arguments: its code stays under 64 MB.
 2. **Board** — the 4x12-board hybrid farm (48 chips of 4x2 QPEs, 1536
-   PEs) -> ``compile_board`` -> ``ChipSim``, 64 ticks dense, auto, and
-   sparse with the Pallas prefix-sum kernel, with the same checks.
+   PEs) at the benchmark cell's widths (512 neurons per NEF PE, 64
+   hidden units) -> ``compile_for_board`` -> ``ChipSim``, 64 ticks
+   dense, auto, and sparse with the Pallas prefix-sum kernel, with the
+   same checks.
 3. **Serving** — ``FleetEngine`` on ``adaptive_scenario`` with the width
    ladder (16, 32, 64) serves 32 Poisson sessions to completion: none is
    dropped and the health verdict is not ``critical``.
@@ -40,6 +42,7 @@ import numpy as np
 SYNFIRE_PES = 4096
 SYNFIRE_TICKS = 256
 BOARD, BOARD_CHIP = "4x12", "4x2"
+BOARD_NEURONS, BOARD_HIDDEN = 512, 64
 BOARD_TICKS = 64
 CPU_TICKS = 64
 FLEET_LEVELS = (16, 32, 64)
@@ -168,15 +171,17 @@ def synfire_phase() -> None:
 
 
 def board_phase() -> None:
-    from repro.board import BoardSpec, compile_board
+    from repro.board import compile_for_board
     from repro.chip.chip import ChipSim
     from repro.chip.workloads import hybrid_farm_board_graph
-    board = BoardSpec.parse(BOARD, chip=BOARD_CHIP)
-    log(f"board {BOARD} of {BOARD_CHIP} chips ({board.n_pes} PEs), "
-        f"hybrid farm, {BOARD_TICKS} ticks")
+    log(f"board {BOARD} of {BOARD_CHIP} chips, hybrid farm of "
+        f"{BOARD_NEURONS} neurons and {BOARD_HIDDEN} hidden units a "
+        f"channel, {BOARD_TICKS} ticks")
     t0 = time.perf_counter()
-    program = compile_board(hybrid_farm_board_graph(board), board)
-    log(f"  build + compile_board {time.perf_counter() - t0:.1f} s")
+    program = compile_for_board(hybrid_farm_board_graph(
+        BOARD, chip=BOARD_CHIP, n_neurons=BOARD_NEURONS,
+        hidden=BOARD_HIDDEN))
+    log(f"  build + compile_for_board {time.perf_counter() - t0:.1f} s")
     runs = {
         "dense": timed_runs(ChipSim(program, exec_mode="dense"),
                             BOARD_TICKS, "dense"),

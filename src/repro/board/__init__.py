@@ -14,6 +14,7 @@ unchanged, workload-agnostic ``ChipSim`` engine runs a whole board:
     board = BoardSpec.parse("4x12", chip="4x2")      # 48 chips, 1536 PEs
     graph = hybrid_farm_board_graph(board)
     sim   = ChipSim(compile_board(graph, board))
+    # or, from the graph alone: ChipSim(compile_for_board(graph))
     recs  = sim.run(64)          # + load_xchip / flits_xchip / e_noc_xchip
     table = chip_power_table(sim, recs)              # incl. noc["xchip"]
 
@@ -21,8 +22,10 @@ A 1x1 board is bit-identical to the single-chip ``compile`` + ``ChipSim``
 path (tests/test_board.py) — the board layer adds tiers, not drift.
 """
 from repro.board.partition import Partition, partition
-from repro.board.route import BoardProgram, chip_tree, compile_board
+from repro.board.route import (BoardProgram, chip_tree, compile_board,
+                               compile_for_board)
 from repro.board.spec import BoardNoc, BoardSpec, xlink_spec
 
 __all__ = ["BoardSpec", "BoardNoc", "xlink_spec", "Partition", "partition",
-           "BoardProgram", "chip_tree", "compile_board"]
+           "BoardProgram", "chip_tree", "compile_board",
+           "compile_for_board"]

@@ -400,3 +400,15 @@ def compile_board(graph: NetGraph, board: Optional[BoardSpec] = None,
                         board=board, part=part, chip_of_pe=chip_of_pe,
                         coords_local=coords_local, tree_links_x=tl_x,
                         path_hops=path_hops, route=route)
+
+
+def compile_for_board(graph: NetGraph) -> BoardProgram:
+    """``compile_board`` of ``graph`` onto the board it was sized for
+    (``graph.board``, set by a board-sized builder such as
+    ``repro.chip.workloads.hybrid_farm_board_graph``), placed by the
+    greedy snake fill alone (``refine=False``): populations land on
+    chips in the order the builder laid them out."""
+    if graph.board is None:
+        raise ValueError(f"graph {graph.name!r} was sized for no board; "
+                         "call compile_board with a BoardSpec")
+    return compile_board(graph, graph.board, refine=False)

@@ -193,7 +193,7 @@ class ChipSim:
                 build_args = getattr(self.program.graph.semantics,
                                      "build_args", None)
                 if build_args:
-                    span.set_metadata(**build_args())
+                    span.set_metadata(**build_args(self.program))
             self._steppers[key] = (init, step, params)
         return self._steppers[key]
 
@@ -301,10 +301,13 @@ class ChipSim:
                 rec[f"touched_links_{tier}"] = jnp.matmul(
                     hit, m, precision="highest")
             if tiered:
-                rec["load_xchip"] = (rec["link_load"] * xmask).sum(axis=-1)
-                rec["flits_xchip"] = (rec["link_flits"] * xmask).sum(axis=-1)
-                rec["e_noc_xchip"] = noc.xchip_energy_j(packets,
-                                                        tree_links_x, pb)
+                with jax.named_scope("xchip"):
+                    rec["load_xchip"] = (rec["link_load"]
+                                         * xmask).sum(axis=-1)
+                    rec["flits_xchip"] = (rec["link_flits"]
+                                          * xmask).sum(axis=-1)
+                    rec["e_noc_xchip"] = noc.xchip_energy_j(
+                        packets, tree_links_x, pb)
             return rec
 
         return init, chip_tick
@@ -365,7 +368,8 @@ class ChipSim:
         the host span ``chip.run`` (args: ``n_ticks``, the resolved
         ``exec_mode`` and ``noc_mode``, ``cached``) holding
         ``chip.build`` (a new stepper; args: the semantics'
-        ``build_args()``, if it has one), ``chip.compile`` (a new program)
+        ``build_args(program)``, if it has one), ``chip.compile`` (a new
+        program)
         and ``chip.dispatch``.
         """
         settings = (seed, noc_mode, link_load_impl, exec_mode)

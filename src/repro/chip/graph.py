@@ -110,11 +110,17 @@ class TickSemantics(Protocol):
 
 @dataclass
 class NetGraph:
-    """Ordered populations + typed projections + tick semantics."""
+    """Ordered populations + typed projections + tick semantics.
+
+    ``board`` is the ``repro.board.BoardSpec`` a board-sized builder
+    (``repro.chip.workloads.hybrid_farm_board_graph``) sized the graph
+    for, which ``repro.board.compile_for_board`` compiles it onto; None
+    for a graph sized for no board."""
     populations: list
     projections: list
     semantics: Optional[TickSemantics] = None
     name: str = "net"
+    board: object = None
 
     def __post_init__(self):
         known, dup = set(), set()

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -74,7 +75,7 @@ class SynfireSemantics:
         sp = self.net.params
         return DVFSController(sp.l_th1, sp.l_th2)
 
-    def build_args(self) -> dict:
+    def build_args(self, program: ChipProgram) -> dict:
         """Args of ``ChipSim``'s ``chip.build`` host span: the storage
         width of the synaptic slabs (``core.snn.slab_dtype``)."""
         return {"w_dtype": str(self.net.w_ff.dtype)}
@@ -506,18 +507,25 @@ class HybridFarmSemantics:
     Sec. II hybrid at board scale (one channel = ``HybridSemantics``).
 
     All channels share one ensemble build (weights, LIF constants, drive
-    table) but integrate phase-shifted copies of the drive, so spike
+    table) but integrate phase-shifted copies of the drive (channel k
+    starts ``k * phase_step_ticks`` ticks into the table), so spike
     times — and therefore NoC traffic — decorrelate across the mesh.
     States batch the channel axis: (K, N) arrays, one ``lif_step_ref``
     call for the whole farm.  Each NEF PE emits at most one graded
     spike-vector packet per tick (16 b per spike), consumed by its paired
     MLP PE on the next tick; energy follows activity on the NoC and in
     the datapath, exactly as in the single-channel semantics.
+
+    The tick's stages carry the synfire tick's names where they mean the
+    same: ``background`` (the drive lookup), ``neuron`` (LIF),
+    ``synapse`` (the event-MAC GEMM) and ``route`` (per-PE placement,
+    energies, records).
     """
     ens: object                         # core.nef.Ensemble (shared build)
     w_eff: jnp.ndarray                  # (N, hidden) f32 dequantized
     drive_fx: jnp.ndarray               # (T, N) int32 s16.15 encode drive
     n_pairs: int
+    phase_step_ticks: int = 17
     bits_per_spike: int = 16
     t_sys_s: float = 1e-3
 
@@ -534,6 +542,20 @@ class HybridFarmSemantics:
                 "ref": jnp.zeros((K, N), jnp.int32),
                 "spike_buf": jnp.zeros((K, N), jnp.float32)}
 
+    def build_args(self, program: ChipProgram) -> dict:
+        """Args of ``ChipSim``'s ``chip.build`` host span: the farm's
+        widths, and the board and link count of each NoC tier it was
+        compiled onto."""
+        args = {"n_pairs": self.n_pairs, "n_neurons": self.ens.n_neurons,
+                "hidden": int(self.w_eff.shape[1])}
+        board = getattr(program, "board", None)
+        if board is not None:
+            args["board"] = (f"{board.chips_x}x{board.chips_y} chips of "
+                             f"{board.chip.width}x{board.chip.height} QPEs")
+        for tier, mask in program.noc.tier_masks().items():
+            args[f"links_{tier}"] = int(np.count_nonzero(mask))
+        return args
+
     def make_tick(self, program: ChipProgram, *, dvfs, em, key):
         ens = self.ens
         K, N, D = self.n_pairs, ens.n_neurons, ens.dims
@@ -542,7 +564,7 @@ class HybridFarmSemantics:
         drive = self.drive_fx
         T = drive.shape[0]
         # co-prime phase offsets decorrelate the channels' spike times
-        offsets = jnp.asarray((np.arange(K) * 17) % T)
+        offsets = jnp.asarray((np.arange(K) * self.phase_step_ticks) % T)
         nef_np, mlp_np = self._pe_ids(program)
         n_neur = jnp.zeros(P).at[jnp.asarray(nef_np)].set(
             float(N)).astype(jnp.int32)
@@ -566,49 +588,54 @@ class HybridFarmSemantics:
                 [nef_vals, mlp_vals, jnp.zeros(1, jnp.float32)])[perm]
 
         def tick(state, t):
-            dfx = drive[(t + offsets) % T]                    # (K, N)
-            v, ref, spk = lif_step_ref(state["v"], state["ref"], dfx,
-                                       **ens.lif)
-            spk_f = spk.astype(jnp.float32)                   # (K, N)
-            n_spk = spk_f.sum(axis=1)                         # (K,)
-            active = (n_spk > 0).astype(jnp.float32)
-            bits_out = self.bits_per_spike * n_spk
+            with jax.named_scope("background"):
+                dfx = drive[(t + offsets) % T]                # (K, N)
+            with jax.named_scope("neuron"):
+                v, ref, spk = lif_step_ref(state["v"], state["ref"], dfx,
+                                           **ens.lif)
+                spk_f = spk.astype(jnp.float32)               # (K, N)
+                n_spk = spk_f.sum(axis=1)                     # (K,)
+                active = (n_spk > 0).astype(jnp.float32)
+                bits_out = self.bits_per_spike * n_spk
 
             # MLP PEs consume LAST tick's spike vectors (1-tick transport)
-            arr = state["spike_buf"]                          # (K, N)
-            h = jnp.matmul(arr, w_eff, precision="highest")  # (K, hidden)
-            n_arr = arr.sum(axis=1)                           # (K,)
-            mac_events = n_arr * hidden
-            bits_in = self.bits_per_spike * n_arr
+            with jax.named_scope("synapse"):
+                arr = state["spike_buf"]                      # (K, N)
+                h = jnp.matmul(arr, w_eff,
+                               precision="highest")           # (K, hidden)
+                n_arr = arr.sum(axis=1)                       # (K,)
+                mac_events = n_arr * hidden
+                bits_in = self.bits_per_spike * n_arr
 
-            packets = place2(active, zk)
-            payload_bits = place2(bits_out, zk)
-            fifo = place2(jnp.full(K, float(N)), n_arr)
-            pl = dvfs.select_pl(fifo.astype(jnp.int32))
-            snn_ev = place2(n_spk * D, zk)
-            syn_ev = place2(n_spk * D, mac_events)
-            e_dvfs = em.tick_energy(pl, n_neur, snn_ev, dvfs=True)
-            e_pl3 = em.tick_energy(jnp.full((P,), 2), n_neur, snn_ev,
-                                   dvfs=False)
-            e_mac = place2(zk, mac_dynamic_energy_j(mac_events))
+            with jax.named_scope("route"):
+                packets = place2(active, zk)
+                payload_bits = place2(bits_out, zk)
+                fifo = place2(jnp.full(K, float(N)), n_arr)
+                pl = dvfs.select_pl(fifo.astype(jnp.int32))
+                snn_ev = place2(n_spk * D, zk)
+                syn_ev = place2(n_spk * D, mac_events)
+                e_dvfs = em.tick_energy(pl, n_neur, snn_ev, dvfs=True)
+                e_pl3 = em.tick_energy(jnp.full((P,), 2), n_neur, snn_ev,
+                                       dvfs=False)
+                e_mac = place2(zk, mac_dynamic_energy_j(mac_events))
 
-            rec = {
-                "packets": packets,
-                "payload_bits": payload_bits,
-                "graded_bits_out": place2(bits_out, zk),
-                "graded_bits_in": place2(zk, bits_in),
-                "pl": pl,
-                "n_fifo": fifo,
-                "syn_events": syn_ev,
-                "n_spk": n_spk.sum(),
-                "hidden_out": h,
-                "e_dvfs_baseline": e_dvfs["baseline"],
-                "e_dvfs_neuron": e_dvfs["neuron"],
-                "e_dvfs_synapse": e_dvfs["synapse"] + e_mac,
-                "e_pl3_baseline": e_pl3["baseline"],
-                "e_pl3_neuron": e_pl3["neuron"],
-                "e_pl3_synapse": e_pl3["synapse"] + e_mac,
-            }
+                rec = {
+                    "packets": packets,
+                    "payload_bits": payload_bits,
+                    "graded_bits_out": place2(bits_out, zk),
+                    "graded_bits_in": place2(zk, bits_in),
+                    "pl": pl,
+                    "n_fifo": fifo,
+                    "syn_events": syn_ev,
+                    "n_spk": n_spk.sum(),
+                    "hidden_out": h,
+                    "e_dvfs_baseline": e_dvfs["baseline"],
+                    "e_dvfs_neuron": e_dvfs["neuron"],
+                    "e_dvfs_synapse": e_dvfs["synapse"] + e_mac,
+                    "e_pl3_baseline": e_pl3["baseline"],
+                    "e_pl3_neuron": e_pl3["neuron"],
+                    "e_pl3_synapse": e_pl3["synapse"] + e_mac,
+                }
             new_state = {"v": v, "ref": ref, "spike_buf": spk_f}
             return new_state, rec
 
@@ -616,15 +643,22 @@ class HybridFarmSemantics:
 
 
 def hybrid_farm_graph(n_pairs: int, n_neurons: int = 32, hidden: int = 16,
-                      n_ticks: int = 256, seed: int = 0) -> NetGraph:
+                      table_ticks: int = 256, seed: int = 0,
+                      amplitude: float = 0.8, period_ticks: float = 97,
+                      phase_step_ticks: int = 17) -> NetGraph:
     """``n_pairs`` independent NEF -> event-MAC channels as one graph
     (2 * n_pairs populations).  All NEF populations are laid out before
     all MLP populations, so channel k's projection crosses a long stretch
     of the snake — board-scale multicast traffic over real mesh links.
+
+    The drive is a sine of ``amplitude`` and ``period_ticks``, tabled
+    over ``table_ticks`` ticks (the table wraps; a whole number of
+    periods wraps without a jump); channel k reads it
+    ``k * phase_step_ticks`` ticks ahead.
     """
     ens = build_ensemble(n_neurons, 1, seed=seed)
-    t = np.arange(n_ticks)
-    x = 0.8 * np.sin(2 * np.pi * t / 97)[:, None]
+    t = np.arange(table_ticks)
+    x = amplitude * np.sin(2 * np.pi * t / period_ticks)[:, None]
     drive_fx = encode_drive(ens, x, use_mac=True)
     rng = np.random.default_rng(seed)
     w = jnp.asarray(rng.standard_normal((n_neurons, hidden)) * 0.1,
@@ -642,7 +676,8 @@ def hybrid_farm_graph(n_pairs: int, n_neurons: int = 32, hidden: int = 16,
                         bits_per_packet=16 * n_neurons, delay_ticks=1)
              for k in range(n_pairs)]
     sem = HybridFarmSemantics(ens=ens, w_eff=w_eff, drive_fx=drive_fx,
-                              n_pairs=n_pairs)
+                              n_pairs=n_pairs,
+                              phase_step_ticks=phase_step_ticks)
     return NetGraph(populations=pops, projections=projs, semantics=sem,
                     name=f"hybrid_farm{n_pairs}")
 
@@ -681,15 +716,25 @@ def dnn_board_graph(board, layer: dict | None = None,
 
 
 def hybrid_farm_board_graph(board, n_neurons: int = 32, hidden: int = 16,
-                            n_ticks: int = 256, seed: int = 0) -> NetGraph:
+                            table_ticks: int = 256, seed: int = 0,
+                            chip: str = "2x2", **drive) -> NetGraph:
     """Hybrid NEF -> event-MAC farm sized to a board: one channel per PE
     pair.  All NEF populations precede all MLP populations, so after
     partitioning most channels span chips — worst-case (traffic-heavy)
     layout for the chip-to-chip tier, which is what makes it the board
-    benchmark's headline workload."""
-    return hybrid_farm_graph(n_pairs=max(1, board.n_pes // 2),
-                             n_neurons=n_neurons, hidden=hidden,
-                             n_ticks=n_ticks, seed=seed)
+    benchmark's headline workload.
+
+    ``board`` is a ``BoardSpec`` or its ``BoardSpec.parse`` text ("4x12",
+    of ``chip`` chips); ``drive`` holds ``hybrid_farm_graph``'s drive
+    arguments (``amplitude``, ``period_ticks``, ``phase_step_ticks``)."""
+    from repro.board import BoardSpec
+    if isinstance(board, str):
+        board = BoardSpec.parse(board, chip=chip)
+    graph = hybrid_farm_graph(n_pairs=max(1, board.n_pes // 2),
+                              n_neurons=n_neurons, hidden=hidden,
+                              table_ticks=table_ticks, seed=seed, **drive)
+    graph.board = board
+    return graph
 
 
 def board_workload(graph: NetGraph, board, n_ticks: int = 64,

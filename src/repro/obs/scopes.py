@@ -2,10 +2,13 @@
 
 ``ChipSim``'s tick runs every stage under a ``jax.named_scope`` whose
 name is in ``STAGES``: ``chip_tick`` holds ``semantics`` (the workload's
-tick), ``learn`` (plastic programs only) and ``noc``; the synfire tick
+tick), ``learn`` (plastic programs only) and ``noc`` (with ``xchip``, the
+chip-to-chip tier of a board); the synfire tick
 (``repro.core.snn.make_synfire_tick``) splits ``semantics`` into
 ``fifo``, ``synapse`` (the event tick's cond branches are ``compressed``
-and ``dense_fallback``), ``background``, ``neuron`` and ``route``.
+and ``dense_fallback``), ``background``, ``neuron`` and ``route``, and
+the hybrid farm's tick (``repro.chip.workloads.HybridFarmSemantics``)
+into ``background``, ``neuron``, ``synapse`` and ``route``.
 
 XLA keeps the scopes in each instruction's ``op_name`` metadata, but a
 device op in a profiler trace read through ``jax.profiler.ProfileData``
@@ -20,9 +23,9 @@ from __future__ import annotations
 
 import re
 
-STAGES = frozenset({"chip_tick", "semantics", "learn", "noc", "fifo",
-                    "synapse", "compressed", "dense_fallback", "background",
-                    "neuron", "route"})
+STAGES = frozenset({"chip_tick", "semantics", "learn", "noc", "xchip",
+                    "fifo", "synapse", "compressed", "dense_fallback",
+                    "background", "neuron", "route"})
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _OP = re.compile(r'^\s*(?:ROOT )?%?([^\s=]+) = [^\n]*?op_name="([^"]*)"',
                  re.M)

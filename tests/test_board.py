@@ -107,7 +107,7 @@ def test_chip_tree_is_a_tree():
 def farm_2x2():
     board = BoardSpec(2, 2, chip=MeshSpec(2, 2))
     graph = hybrid_farm_board_graph(board, n_neurons=16, hidden=8,
-                                    n_ticks=64)
+                                    table_ticks=64)
     rep = board_workload(graph, board, n_ticks=60)
     return board, rep
 
@@ -145,6 +145,49 @@ def _onchip_energy_j(prog, recs):
     tl_on = (prog.sinc.tree_links - prog.tree_links_x).astype(np.float64)
     bits = (pk * tl_on * pbits).sum(axis=-1)
     return bits * prog.noc.spec.pj_per_bit_hop * 1e-12
+
+
+# sha256 (first 16 hex digits) of each farm_2x2 record as the farm
+# produced it while its drive was hard-coded (amplitude 0.8, period 97,
+# phase step 17 ticks); ``hidden_out``, a float GEMM whose last bits
+# follow the CPU's thread partitioning, by its sum
+FARM_2X2_DIGESTS = {
+    "active_frac": "224b9369a735067c", "active_sources": "eb095380f8d20316",
+    "e_dvfs_baseline": "fe29135ab0eaf2a0", "e_dvfs_neuron": "eb7fe1995c8fb49e",
+    "e_dvfs_synapse": "3a1d2407d62c4353", "e_noc": "e122a574dfe4655d",
+    "e_noc_xchip": "21a685f6ad85aeff", "e_pl3_baseline": "85fb0c83b6917007",
+    "e_pl3_neuron": "58f315e5e0cb7791", "e_pl3_synapse": "e63c4e6b09f2c203",
+    "flits_xchip": "46e6bcb8bdbf45ac", "graded_bits_in": "2253632cf884e4c2",
+    "graded_bits_out": "e5ad06e4e318339d", "link_flits": "05ed3b95f2ea4709",
+    "link_load": "05ed3b95f2ea4709", "load_xchip": "46e6bcb8bdbf45ac",
+    "n_fifo": "bc5c46004a0d93f9", "n_spk": "a734da04ca756804",
+    "packets": "ccb8951138651ee5", "payload_bits": "e5ad06e4e318339d",
+    "pl": "0299f757a85a1aad", "syn_events": "37c1d380790348f4",
+    "touched_links": "9ff47629483e3f46",
+    "touched_links_onchip": "211c754f32c344dd",
+    "touched_links_xchip": "d5f2460cae7128a6"}
+FARM_2X2_HIDDEN_SUM = 370.8804432605393
+
+
+def test_farm_drive_defaults_keep_the_records(farm_2x2):
+    """The farm's drive arguments at their defaults give the records of
+    the hard-coded drive they replaced, bit for bit."""
+    import hashlib
+    recs = farm_2x2[1]["recs"]
+    assert set(recs) == set(FARM_2X2_DIGESTS) | {"hidden_out"}
+    got = {k: hashlib.sha256(np.asarray(v).tobytes()).hexdigest()[:16]
+           for k, v in recs.items() if k != "hidden_out"}
+    assert got == FARM_2X2_DIGESTS
+    hidden = np.asarray(recs["hidden_out"], np.float64)
+    assert hidden.shape == (60, 32, 8)
+    assert hidden.sum() == pytest.approx(FARM_2X2_HIDDEN_SUM, rel=1e-9)
+    # the defaults are the drive: spelled out, they give the same program
+    explicit = hybrid_farm_board_graph(
+        farm_2x2[0], n_neurons=16, hidden=8, table_ticks=64, amplitude=0.8,
+        period_ticks=97, phase_step_ticks=17)
+    again = ChipSim(compile_board(explicit, farm_2x2[0])).run(60)
+    for k in recs:
+        assert np.array_equal(np.asarray(again[k]), np.asarray(recs[k])), k
 
 
 def test_power_table_reports_xchip_tier(farm_2x2):

@@ -116,3 +116,36 @@ def test_probed_run_compiles_once(tmp_path):
         tmp_path / "b")
     assert [n for n, _ in events] == ["chip.run", "chip.compile",
                                       "chip.dispatch"]
+
+
+FARM = {"background", "neuron", "synapse", "route"}
+
+
+@pytest.fixture(scope="module")
+def farm_board_sim():
+    from repro.board import BoardSpec, compile_for_board
+    from repro.chip.mesh_noc import MeshSpec
+    from repro.chip.workloads import hybrid_farm_board_graph
+    board = BoardSpec(2, 2, chip=MeshSpec(2, 1))
+    return ChipSim(compile_for_board(hybrid_farm_board_graph(
+        board, n_neurons=16, hidden=8)))
+
+
+def test_farm_scan_holds_its_stages_and_the_xchip_tier(farm_board_sim):
+    farm_board_sim.run(8)
+    assert _stages_of_last_scan() == ENGINE | FARM | {"xchip"}
+    paths = set(scopes.table()["jit_scan"].values())
+    assert {f"chip_tick/semantics/{s}" for s in FARM} | {
+        "chip_tick/noc", "chip_tick/noc/xchip"} <= paths
+
+
+def test_farm_build_args_reach_the_build_span(farm_board_sim, tmp_path):
+    sim = ChipSim(farm_board_sim.program)
+    events = _host_events(lambda: sim.run(6), tmp_path)
+    assert [n for n, _ in events] == ["chip.run", "chip.build",
+                                      "chip.compile", "chip.dispatch"]
+    args = events[1][1]
+    assert args["n_pairs"] == 16
+    assert (args["n_neurons"], args["hidden"]) == (16, 8)
+    assert args["board"] == "2x2 chips of 2x1 QPEs"
+    assert (args["links_onchip"], args["links_xchip"]) == (4 * 2, 8)

@@ -101,7 +101,7 @@ def test_engine_sparse_dense_records_identical():
     (dynamic graded payloads included via the farm workload)."""
     for graph in (synfire_graph(12),
                   hybrid_farm_graph(n_pairs=6, n_neurons=16, hidden=8,
-                                    n_ticks=64)):
+                                    table_ticks=64)):
         sim = ChipSim(compile_graph(graph))
         a = sim.run(60, noc_mode="sparse")
         b = sim.run(60, noc_mode="dense")
@@ -127,7 +127,8 @@ def test_golden_synfire_bit_identical_through_sparse_path():
 def test_auto_mode_picks_sparse_for_sparse_trees():
     # board scale (224 links, density ~0.009): sparse
     sim = ChipSim(compile_graph(
-        hybrid_farm_graph(n_pairs=128, n_neurons=8, hidden=4, n_ticks=16)))
+        hybrid_farm_graph(n_pairs=128, n_neurons=8, hidden=4,
+                          table_ticks=16)))
     assert sim.program.sinc.density < 0.25
     assert sim.use_sparse_noc() is True
     assert sim.use_sparse_noc("dense") is False
@@ -173,7 +174,7 @@ def test_dense_inc_materializes_lazily():
 def test_hybrid_farm_runs_and_conserves_payload():
     """The board-scale hybrid farm honours the record contract: graded
     payload bits emitted == consumed one transport tick later."""
-    g = hybrid_farm_graph(n_pairs=8, n_neurons=16, hidden=8, n_ticks=64)
+    g = hybrid_farm_graph(n_pairs=8, n_neurons=16, hidden=8, table_ticks=64)
     sim = ChipSim(compile_graph(g))
     recs = jax.block_until_ready(sim.run(60))
     out = np.asarray(recs["graded_bits_out"]).sum(axis=1)

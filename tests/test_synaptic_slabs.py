@@ -29,7 +29,7 @@ def test_slab_width_follows_the_weights(kw, dtype):
     net = build_synfire(0, n_pes=2, **kw)
     assert net.w_ff.dtype == dtype and net.w_inh.dtype == dtype
     # the width ``ChipSim`` shows on its ``chip.build`` span
-    assert SynfireSemantics(net).build_args() == {
+    assert SynfireSemantics(net).build_args(program=None) == {
         "w_dtype": jnp.dtype(dtype).name}
     w_exc, w_inh = kw.get("w_exc", 0.075), kw.get("w_inh", -0.30)
     assert set(np.unique(np.asarray(net.w_ff))) == {

@@ -122,6 +122,41 @@ def test_synfire_engine_step_takes_weights_as_arguments(settings, one_chip,
         assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.fixture(scope="module")
+def farm4x12():
+    """The benchmark's hybrid farm at cell size: 768 NEF -> event-MAC
+    channels of 512 neurons and 64 hidden units on the 4x12 board of
+    4x2-QPE chips, the drive tabled over one 97-tick period."""
+    from repro.board import compile_for_board
+    from repro.chip.workloads import hybrid_farm_board_graph
+    return compile_for_board(hybrid_farm_board_graph(
+        "4x12", chip="4x2", n_neurons=512, hidden=64, table_ticks=97))
+
+
+def test_farm_engine_step_compiles_at_cell_size(one_chip, farm4x12):
+    """One tick of the 4x12-board farm compiles for v5e with ChipSim's
+    defaults (the dense NoC einsum: chip-to-chip links carry up to 192
+    channels each), its drive table, MLP weights and NoC incidence
+    arguments of the program, not code."""
+    sem = farm4x12.graph.semantics
+    assert (farm4x12.n_pes, sem.ens.n_neurons, sem.w_eff.shape[1]) == (
+        1536, 512, 64)
+    init, step, params = ChipSim(farm4x12).make_stepper()
+    as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    compiled = jax.jit(step).lower(
+        jax.tree.map(as_sds, params), jax.tree.map(as_sds, init),
+        _sds((), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    param_bytes = sum(p.size * p.dtype.itemsize for p in params)
+    assert param_bytes >= (sem.drive_fx.nbytes + sem.w_eff.nbytes
+                           + 4 * farm4x12.n_pes * farm4x12.noc.n_links)
+    assert mem.argument_size_in_bytes >= param_bytes
+    assert mem.generated_code_size_in_bytes < param_bytes // 4
+    # the event-MAC GEMM, (768, 512) spikes by (512, 64) weights
+    assert re.search(r"f32\[768,64\]\S* (?:convolution|dot|fusion)",
+                     compiled.as_text())
+
+
 def _materialised(hlo: str) -> set:
     """Shapes (``s32[256,200,250]``) of the arrays an optimised module
     writes to memory: results of instructions outside fused
