@@ -147,7 +147,7 @@ def synfire_phase() -> None:
     program = compile_graph(synfire_graph(SYNFIRE_PES))
     net = program.graph.semantics.net
     log(f"  build + compile_graph {time.perf_counter() - t0:.1f} s; "
-        f"weights {(net.w_ff.nbytes + net.w_inh.nbytes) / 2**30:.2f} GiB")
+        f"synapse masks {net.synapse_bytes / 2**20:.1f} MiB")
     dense = ChipSim(program, exec_mode="dense")
     auto = ChipSim(program)
     pallas = ChipSim(program, link_load_impl="pallas", event_impl="pallas")

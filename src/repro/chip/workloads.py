@@ -76,9 +76,10 @@ class SynfireSemantics:
         return DVFSController(sp.l_th1, sp.l_th2)
 
     def build_args(self, program: ChipProgram) -> dict:
-        """Args of ``ChipSim``'s ``chip.build`` host span: the storage
-        width of the synaptic slabs (``core.snn.slab_dtype``)."""
-        return {"w_dtype": str(self.net.w_ff.dtype)}
+        """Args of ``ChipSim``'s ``chip.build`` host span: how the
+        synapses are stored (``core.snn.pack_mask``) and their bytes."""
+        return {"w_store": "bitmask",
+                "synapse_bytes": self.net.synapse_bytes}
 
 
 def synfire_graph(n_pes: int = 8, seed: int = 0,

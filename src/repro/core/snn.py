@@ -8,8 +8,10 @@ processing; after the busy window t_sp the PE returns to PL1 and sleeps.
 Arithmetic is SpiNNaker-style s16.15 fixed point: the LIF update uses
 exactly the kernel math (kernels/lif/ref.py — bit-identical to the Pallas
 kernel), the membrane decay constant comes from the exp accelerator
-(kernels/explog), and synaptic-event accumulation is an integer matmul —
-the event-driven MAC-array mode of Sec. II.
+(kernels/explog), and synaptic-event accumulation is integer — the
+event-driven MAC-array mode of Sec. II — counted with AND and popcount
+over bit-packed connectivity masks, every synapse of a projection
+holding one weight (``synaptic_current``).
 
 The synfire chain (Fig. 16, Table II): 8 PEs in a ring; per PE one
 excitatory population (200) and one inhibitory population (50); exc of PE i
@@ -34,8 +36,8 @@ record assembly) stays dense: on XLA CPU a fused elementwise pass over
 all P PEs costs far less than gather/scatter round trips.  Records are
 bitwise identical to the dense tick (integer accumulation is
 reassociation-exact; skipped PEs receive exactly the zero input the
-dense einsum computes for them), and a ``lax.cond`` falls back to the
-dense formulas whenever activity overflows the buffer.
+dense synaptic current computes for them), and a ``lax.cond`` falls back
+to the dense formulas whenever activity overflows the buffer.
 """
 from __future__ import annotations
 
@@ -160,10 +162,13 @@ def gauss_noise_fx(key, t, shape: tuple, sigma_fx: int) -> jnp.ndarray:
 @dataclass
 class SynfireNet:
     params: paper.SynfireParams
-    # synaptic slabs, s16.15 in int16 when every weight fits, else int32
-    # (``slab_dtype``); the einsums accumulate in int32 either way
-    w_ff: jnp.ndarray        # (P, 200, 250) s16.15: prev-exc -> [exc|inh]
-    w_inh: jnp.ndarray       # (P, 50, 200) s16.15 (negative)
+    # bit-packed connectivity masks (``pack_mask``): bit b of word w
+    # stands for source 32w+b, ``pack_spikes``'s order; every synapse of
+    # a projection holds the one s16.15 weight given beside its mask
+    m_ff: jnp.ndarray        # (7, P, 250) uint32: prev-exc -> [exc|inh]
+    m_inh: jnp.ndarray       # (2, P, 200) uint32
+    w_exc_fx: int            # the weight of every set bit of m_ff
+    w_inh_fx: int            # ... of m_inh (negative)
     deg_ff: jnp.ndarray      # (P, 200) int32: out-degree of each prev-exc source
     deg_inh: jnp.ndarray     # (P, 50) int32
     lif: dict
@@ -174,16 +179,46 @@ class SynfireNet:
     kicks_per_tick: int = 0
     kick_fx: int = 0
 
+    @property
+    def synapse_bytes(self) -> int:
+        """Bytes of the synaptic masks."""
+        return self.m_ff.nbytes + self.m_inh.nbytes
 
-def slab_dtype(*slabs: np.ndarray) -> type:
-    """The narrowest exact storage of s16.15 synaptic slabs: ``int16``
-    when every weight fits it (the synfire weights do, |w| <= 9830),
-    else ``int32``.  The synaptic matrix-vector products stream every
-    slab from memory on every tick, so the storage width is their cost;
-    they accumulate in int32, so the width never changes a sum."""
-    lo, hi = np.iinfo(np.int16).min, np.iinfo(np.int16).max
-    fits = all(s.min() >= lo and s.max() <= hi for s in slabs)
-    return np.int16 if fits else np.int32
+
+def pack_mask(conn: np.ndarray) -> np.ndarray:
+    """Bit-pack a connectivity slab ``(P, n_src, n_tgt)`` of bools into
+    uint32 words ``(words(n_src), P, n_tgt)``: bit b of word w is source
+    32w+b, the order ``pack_spikes`` gives the arrivals.  The word axis
+    leads, so that the two large axes fill the TPU's (8, 128) tiles."""
+    P_, n, m = conn.shape
+    w = spike_words(n)
+    octets = np.packbits(conn, axis=1, bitorder="little")     # zero-padded
+    octets = np.pad(octets, ((0, 0), (0, 4 * w - octets.shape[1]), (0, 0)))
+    octets = octets.reshape(P_, w, 4, m).astype(np.uint32)
+    shifts = (8 * np.arange(4, dtype=np.uint32))[:, None]
+    words = np.bitwise_or.reduce(octets << shifts, axis=2)    # (P, w, m)
+    return np.ascontiguousarray(words.transpose(1, 0, 2))
+
+
+def synaptic_current(net: SynfireNet, we: jnp.ndarray, wi: jnp.ndarray,
+                     rows: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Synaptic input current ``(k, N)`` int32 of the packed arrival words
+    ``we`` (k, words(NE)) and ``wi`` (k, words(NI)) through the masks of
+    PEs ``rows`` (all P PEs, in order, when None).
+
+    Every synapse of a projection holds one weight, so the product of
+    the 0/1 arrivals with a weight slab is that weight times the count
+    of arrivals on the mask's set bits: popcount(arrivals & mask),
+    summed over words.  Reading 7 + 2 words per target in place of a
+    slab's 200 + 50 weights is what makes the dense tick cheap."""
+    def count(words, mask):              # (k, W) & (W, k, n) -> (k, n)
+        hits = jax.lax.population_count(words.T[:, :, None] & mask)
+        return hits.astype(jnp.int32).sum(axis=0)
+    m_ff, m_inh = ((net.m_ff, net.m_inh) if rows is None
+                   else (net.m_ff[:, rows], net.m_inh[:, rows]))
+    i_ff = jnp.int32(net.w_exc_fx) * count(we, m_ff)
+    i_in = jnp.int32(net.w_inh_fx) * count(wi, m_inh)
+    return i_ff.at[:, :net.params.n_exc].add(i_in)
 
 
 def build_synfire(seed: int = 0, *, w_exc: float = 0.075, w_inh: float = -0.30,
@@ -216,30 +251,30 @@ def build_synfire(seed: int = 0, *, w_exc: float = 0.075, w_inh: float = -0.30,
     rng = np.random.default_rng(seed)
     P_, NE, NI = sp.n_pes, sp.n_exc, sp.n_inh
     N = sp.neurons_per_core
-    w_ff = np.zeros((P_, NE, N), np.float32)
-    w_inh_m = np.zeros((P_, NI, NE), np.float32)
+    conn_ff = np.zeros((P_, NE, N), bool)
+    conn_inh = np.zeros((P_, NI, NE), bool)
     for p in range(P_):
         # each target neuron draws fan_in_exc sources from prev layer's exc
         for tgt in range(N):
-            src = rng.choice(NE, sp.fan_in_exc, replace=False)
-            w_ff[p, src, tgt] = w_exc
+            conn_ff[p, rng.choice(NE, sp.fan_in_exc, replace=False), tgt] = 1
         for tgt in range(NE):
-            src = rng.choice(NI, sp.fan_in_inh, replace=False)
-            w_inh_m[p, src, tgt] = w_inh
+            conn_inh[p, rng.choice(NI, sp.fan_in_inh, replace=False), tgt] = 1
+    # a zero weight is no synapse: it delivers no synaptic event
+    conn_ff &= w_exc != 0
+    conn_inh &= w_inh != 0
     # v_min bounds hyperpolarization (inhibitory reversal): without it,
     # tonic background inhibition drives the membrane ~3 v_th below rest
     # and the synfire wave dies before completing one ring traversal.
     lif = lif_params_fx(tau_ms=tau_ms, v_th=v_th, v_reset=0.0,
                         ref_ticks=ref_ticks, v_min=v_min)
-    w_ff_fx = np.round(w_ff * FX_ONE).astype(np.int32)
-    w_inh_fx = np.round(w_inh_m * FX_ONE).astype(np.int32)
-    w_dtype = slab_dtype(w_ff_fx, w_inh_fx)
     return SynfireNet(
         params=sp,
-        w_ff=jnp.asarray(w_ff_fx.astype(w_dtype)),
-        w_inh=jnp.asarray(w_inh_fx.astype(w_dtype)),
-        deg_ff=jnp.asarray((w_ff != 0).sum(axis=2), jnp.int32),
-        deg_inh=jnp.asarray((w_inh_m != 0).sum(axis=2), jnp.int32),
+        m_ff=jnp.asarray(pack_mask(conn_ff)),
+        m_inh=jnp.asarray(pack_mask(conn_inh)),
+        w_exc_fx=int(np.round(np.float32(w_exc) * FX_ONE)),
+        w_inh_fx=int(np.round(np.float32(w_inh) * FX_ONE)),
+        deg_ff=jnp.asarray(conn_ff.sum(axis=2), jnp.int32),
+        deg_inh=jnp.asarray(conn_inh.sum(axis=2), jnp.int32),
         lif=lif,
         noise_sigma_fx=int(round(noise_sigma * FX_ONE)),
         stim_ticks=2,
@@ -278,8 +313,8 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
     set (spike arrivals + noise kicks + stimulus targets) is compacted
     into ``src_cap`` index lanes by a two-level tag sort — active
     ``EVENT_CHUNK``-PE chunks first, then per-PE tags on the surviving
-    candidate lanes — and the synaptic einsum gathers only the touched
-    weight slabs, writing back through ONE bounded scatter.  Kick and
+    candidate lanes — and the synaptic current gathers only the touched
+    PEs' synaptic tables, writing back through ONE bounded scatter.  Kick and
     stimulus currents land directly on their compacted lanes (every
     kicked PE is in the input set by construction).  The LIF update and
     the energy pricing stay dense: they are fused elementwise passes,
@@ -287,7 +322,7 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
     falls back (``lax.cond``) to the dense formulas.  Records are
     bitwise identical to ``event=False`` by construction: integer
     accumulation is reassociation-exact, and a skipped PE's synaptic
-    input is exactly the zero row the dense einsum computes for it.
+    input is exactly the zero row the dense current computes for it.
 
     Each stage of either tick runs under a ``jax.named_scope``: ``fifo``,
     ``synapse`` (in the event tick also the compaction and the cond,
@@ -372,11 +407,7 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
 
         with jax.named_scope("synapse"):
             # 3. synaptic accumulation (event-driven integer MAC)
-            i_ff = jnp.einsum("pe,pen->pn", arr_exc, net.w_ff,
-                              preferred_element_type=jnp.int32)
-            i_in = jnp.einsum("pi,pie->pe", arr_inh, net.w_inh,
-                              preferred_element_type=jnp.int32)
-            i_syn = i_ff.at[:, :NE].add(i_in)
+            i_syn = synaptic_current(net, we, wi)
         with jax.named_scope("background"):
             i_syn = add_stim(add_noise(i_syn, t), t)
 
@@ -452,16 +483,12 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
 
             @jax.named_scope("compressed")
             def compressed(ops):
-                arr_e, arr_i = ops
+                we, wi = ops
                 m = valid[:, None]
-                ae = arr_e[safe] * m                   # (cap_eff, NE)
-                ai = arr_i[safe] * m                   # (cap_eff, NI)
-                # gather only the touched weight slabs
-                i_k = jnp.einsum("ke,ken->kn", ae, net.w_ff[safe],
-                                 preferred_element_type=jnp.int32)
-                i_k = i_k.at[:, :NE].add(
-                    jnp.einsum("ki,kie->ke", ai, net.w_inh[safe],
-                               preferred_element_type=jnp.int32))
+                ae = jnp.where(m, we[safe], 0)         # (cap_eff, WE)
+                ai = jnp.where(m, wi[safe], 0)         # (cap_eff, WI)
+                # gather only the touched PEs' synaptic tables
+                i_k = synaptic_current(net, ae, ai, rows=safe)
                 if shot:
                     # every kicked PE is in the input set, so
                     # searchsorted finds its exact lane in the sorted
@@ -478,18 +505,13 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
                                   jnp.int32(0)))
                 # ONE bounded scatter back to the dense current (sentinel
                 # lanes drop); skipped PEs keep the exact zero rows the
-                # dense einsum would compute for them
+                # dense current would compute for them
                 return jnp.zeros((P_, N), jnp.int32).at[idx].set(
                     i_k, mode="drop")
 
             @jax.named_scope("dense_fallback")
             def dense_path(ops):
-                arr_e, arr_i = ops
-                i_ff = jnp.einsum("pe,pen->pn", arr_e, net.w_ff,
-                                  preferred_element_type=jnp.int32)
-                i_syn = i_ff.at[:, :NE].add(
-                    jnp.einsum("pi,pie->pe", arr_i, net.w_inh,
-                               preferred_element_type=jnp.int32))
+                i_syn = synaptic_current(net, *ops)
                 if shot:
                     i_syn = i_syn.at[lanes // N, lanes % N].add(
                         jnp.int32(net.kick_fx))
@@ -501,7 +523,7 @@ def make_synfire_tick(net: SynfireNet, *, dvfs: DVFSController,
                 return i_syn
 
             i_syn = jax.lax.cond((n_src <= cap_eff) & (n_chunks <= kc),
-                                 compressed, dense_path, (arr_exc, arr_inh))
+                                 compressed, dense_path, (we, wi))
         if not shot:
             with jax.named_scope("background"):
                 i_syn = i_syn + gauss_noise_fx(key, t, (P_, N),

@@ -95,7 +95,7 @@ def test_run_emits_host_spans_and_compiles_once(tmp_path):
     args = first[0][1]
     assert args["n_ticks"] == 10 and not args["cached"]
     assert args["exec_mode"] == "event" and args["noc_mode"] == "dense"
-    assert first[1][1]["w_dtype"] == "int16"         # the synaptic slabs
+    assert first[1][1]["w_store"] == "bitmask"       # the synaptic tables
     again = _host_events(lambda: sim.run(10, exec_mode="event"),
                          tmp_path / "b")
     assert [n for n, _ in again] == ["chip.run", "chip.dispatch"]

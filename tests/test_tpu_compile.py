@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.chip.chip import ChipSim
 from repro.chip.compile import compile as compile_graph
-from repro.chip.workloads import synfire_graph
+from repro.chip.workloads import SynfireSemantics, synfire_graph
 from repro.kernels.event_gather.event_gather import onehot_link_accum_pallas
 from repro.kernels.explog.explog import fx_exp_pallas, fx_log_pallas
 from repro.kernels.lif.ops import lif_step
@@ -104,19 +104,26 @@ def synfire256():
 def test_synfire_engine_step_takes_weights_as_arguments(settings, one_chip,
                                                         synfire256):
     """One tick of a 256-PE synfire ring compiles for v5e, and its
-    ~31 MB of synaptic slabs are arguments of the program, not code."""
+    synaptic masks are arguments of the program, each one read, and not
+    code: the program holds no constant of a table's size."""
     net = synfire256.graph.semantics.net
-    weight_bytes = net.w_ff.nbytes + net.w_inh.nbytes
-    n_weights = net.w_ff.size + net.w_inh.size
+    sp = net.params
+    P, NE, N = sp.n_pes, sp.n_exc, sp.neurons_per_core
+    n_weights = P * NE * (N + sp.n_inh)
     sim = ChipSim(synfire256, event_impl="pallas" if settings else None)
     init, step, params = sim.make_stepper(**settings)
+    assert {(7, P, N), (2, P, NE)} <= {
+        a.shape for a in params if a.dtype == jnp.uint32}
     as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
     compiled = jax.jit(step).lower(
         jax.tree.map(as_sds, params), jax.tree.map(as_sds, init),
         _sds((), jnp.int32, one_chip)).compile()
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes >= weight_bytes
-    # under a quarter byte of code per weight, whatever the slab width
+    # jit prunes an argument the program never reads
+    assert mem.argument_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                             for a in params)
+    assert max(_constant_sizes(compiled.as_text())) < net.m_inh.size // 100
+    # under a quarter byte of code per synapse slot
     assert mem.generated_code_size_in_bytes < n_weights // 4
     if settings:
         assert "tpu_custom_call" in compiled.as_text()
@@ -157,6 +164,12 @@ def test_farm_engine_step_compiles_at_cell_size(one_chip, farm4x12):
                      compiled.as_text())
 
 
+def _constant_sizes(hlo: str) -> list:
+    """Element counts of the constants an optimised module holds."""
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* constant\(", hlo)]
+
+
 def _materialised(hlo: str) -> set:
     """Shapes (``s32[256,200,250]``) of the arrays an optimised module
     writes to memory: results of instructions outside fused
@@ -173,31 +186,50 @@ def _materialised(hlo: str) -> set:
     return shapes
 
 
-@pytest.mark.parametrize("exec_mode", ["dense", "event"])
-def test_synfire_scan_streams_int16_slabs(exec_mode, one_chip, synfire256):
-    """The engine's scan over a 256-PE ring reads the int16 slabs it is
-    given: the int16 -> int32 conversion stays fused into the
-    multiply-reduce, never an int32 copy of a slab (a copy hoisted out
-    of the tick loop would be read instead on every tick)."""
-    net = synfire256.graph.semantics.net
-    P, NE, NI, N = (net.params.n_pes, net.params.n_exc, net.params.n_inh,
-                    net.params.neurons_per_core)
-    slabs = {(P, NE, N), (P, NI, NE)}
-    assert {(w.shape, w.dtype) for w in (net.w_ff, net.w_inh)} == {
-        (s, np.dtype(np.int16)) for s in slabs}
-    int32_bytes = 4 * (net.w_ff.size + net.w_inh.size)
-    init, step, params = ChipSim(synfire256).make_stepper(
-        exec_mode=exec_mode)
-    assert {a.shape for a in params if a.dtype == jnp.int16} == slabs
+def _compile_scan(program, exec_mode, sharding):
+    """The engine's 4-tick scan over ``program``, compiled for the
+    described chip; returns the stepper's params and the executable."""
+    init, step, params = ChipSim(program).make_stepper(exec_mode=exec_mode)
 
     def scan(params, init):
         return jax.lax.scan(lambda s, t: step(params, s, t), init,
                             jnp.arange(4))[1]
-    as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
-    compiled = jax.jit(scan).lower(jax.tree.map(as_sds, params),
-                                   jax.tree.map(as_sds, init)).compile()
-    assert compiled.memory_analysis().argument_size_in_bytes < int32_bytes
+    as_sds = lambda x: _sds(x.shape, x.dtype, sharding)
+    return params, jax.jit(scan).lower(jax.tree.map(as_sds, params),
+                                       jax.tree.map(as_sds, init)).compile()
+
+
+def _tiled_bytes(shape, dtype, layout) -> int:
+    """Bytes of an array in a device layout: its minor dimensions padded
+    to the layout's tile."""
+    dims = [shape[i] for i in layout.major_to_minor]
+    tile = layout.tiling[0]
+    for k, t in enumerate(tile, start=len(dims) - len(tile)):
+        dims[k] = -(-dims[k] // t) * t
+    return int(np.prod(dims)) * np.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("exec_mode", ["dense", "event"])
+def test_synfire_scan_streams_bitmasks(exec_mode, one_chip, synfire256):
+    """The engine's scan over a 256-PE ring reads its synapses as
+    bit-packed masks: as the v5e compile lays them out, tiles padded,
+    they take under a tenth of the int16 slabs' bytes, and no
+    slab-shaped array is written."""
+    net = synfire256.graph.semantics.net
+    sp = net.params
+    P, NE, NI, N = sp.n_pes, sp.n_exc, sp.n_inh, sp.neurons_per_core
+    masks = {(7, P, N), (2, P, NE)}
+    assert {(m.shape, m.dtype) for m in (net.m_ff, net.m_inh)} == {
+        (s, np.dtype(np.uint32)) for s in masks}
+    params, compiled = _compile_scan(synfire256, exec_mode, one_chip)
+    tables = [(a, f.layout)
+              for a, f in zip(params, compiled.input_formats[0][0])
+              if a.shape in masks and a.dtype == jnp.uint32]
+    assert {a.shape for a, _ in tables} == masks
+    int16_bytes = 2 * P * NE * (N + NI)
+    assert sum(_tiled_bytes(a.shape, a.dtype, layout)
+               for a, layout in tables) <= int16_bytes // 10
     written = _materialised(compiled.as_text())
-    assert f"s16[{P},{NE},{N}]" in written           # the parameter
-    for shape in slabs:
-        assert f"s32[{','.join(map(str, shape))}]" not in written
+    for shape in ((P, NE, N), (P, NI, NE)):
+        dims = f"[{','.join(map(str, shape))}]"
+        assert not [w for w in written if w.endswith(dims)], dims
