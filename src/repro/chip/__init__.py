@@ -29,6 +29,9 @@ Modules:
   feedforward DNN pipeline, hybrid NEF + event-driven-MAC pipeline (and
   its board-scale ``hybrid_farm_graph`` of independent channels), plus
   ``*_board_graph`` variants sized to a multi-chip board.
+* ``stimulus``  — where a network's drive comes from: a table baked in
+  at build time, or a window streamed through the scan carry (served
+  sessions).
 
 One level up, ``repro.board`` compiles a ``NetGraph`` across a whole
 grid of chips (``compile_board``) into a program this same ``ChipSim``

@@ -33,6 +33,7 @@ from repro.chip.graph import (GRADED, NetGraph, Population, Projection,
                               busy_window_energy, mac_dynamic_energy_j)
 from repro.chip.mapping import synfire_sram_bytes
 from repro.chip.mesh_noc import MeshSpec
+from repro.chip.stimulus import TableStimulus
 from repro.configs import paper
 from repro.core.dvfs import DVFSController
 from repro.core.hybrid import event_mac_energy_j, event_mac_tick
@@ -372,6 +373,10 @@ class HybridSemantics:
     dispatch weight rows to the MAC array.  Ticks with no spikes send
     nothing and multiply nothing — energy follows activity on the NoC and
     in the datapath alike.
+
+    It stays apart from ``HybridFarmSemantics``: it decodes ``xhat`` and
+    records ``spikes`` and ``n_dispatched``, which the farm at 768 x 512
+    must not compute, and a shared tick would branch on its caller.
     """
     ens: object                         # core.nef.Ensemble
     wq: jnp.ndarray                     # (N, hidden) int8
@@ -507,10 +512,12 @@ class HybridFarmSemantics:
     """K independent NEF -> event-MAC channels ticking in lockstep — the
     Sec. II hybrid at board scale (one channel = ``HybridSemantics``).
 
-    All channels share one ensemble build (weights, LIF constants, drive
-    table) but integrate phase-shifted copies of the drive (channel k
-    starts ``k * phase_step_ticks`` ticks into the table), so spike
-    times — and therefore NoC traffic — decorrelate across the mesh.
+    All channels share one ensemble build (weights, LIF constants) and
+    read their drive from ``stimulus`` (``repro.chip.stimulus``).  The
+    benchmarked farm reads a table, channel k ``k * phase_step`` ticks
+    ahead, so spike times — and therefore NoC traffic — decorrelate
+    across the mesh; the served farm (keyword spotting) streams one
+    session's waveform into every channel.
     States batch the channel axis: (K, N) arrays, one ``lif_step_ref``
     call for the whole farm.  Each NEF PE emits at most one graded
     spike-vector packet per tick (16 b per spike), consumed by its paired
@@ -524,9 +531,8 @@ class HybridFarmSemantics:
     """
     ens: object                         # core.nef.Ensemble (shared build)
     w_eff: jnp.ndarray                  # (N, hidden) f32 dequantized
-    drive_fx: jnp.ndarray               # (T, N) int32 s16.15 encode drive
+    stimulus: object                    # chip.stimulus source of the drive
     n_pairs: int
-    phase_step_ticks: int = 17
     bits_per_spike: int = 16
     t_sys_s: float = 1e-3
 
@@ -541,7 +547,8 @@ class HybridFarmSemantics:
         K, N = self.n_pairs, self.ens.n_neurons
         return {"v": jnp.zeros((K, N), jnp.int32),
                 "ref": jnp.zeros((K, N), jnp.int32),
-                "spike_buf": jnp.zeros((K, N), jnp.float32)}
+                "spike_buf": jnp.zeros((K, N), jnp.float32),
+                **self.stimulus.init_state()}
 
     def build_args(self, program: ChipProgram) -> dict:
         """Args of ``ChipSim``'s ``chip.build`` host span: the farm's
@@ -562,10 +569,7 @@ class HybridFarmSemantics:
         K, N, D = self.n_pairs, ens.n_neurons, ens.dims
         hidden = self.w_eff.shape[1]
         P = program.n_pes
-        drive = self.drive_fx
-        T = drive.shape[0]
-        # co-prime phase offsets decorrelate the channels' spike times
-        offsets = jnp.asarray((np.arange(K) * self.phase_step_ticks) % T)
+        drive_at, _ = self.stimulus.reader(K)
         nef_np, mlp_np = self._pe_ids(program)
         n_neur = jnp.zeros(P).at[jnp.asarray(nef_np)].set(
             float(N)).astype(jnp.int32)
@@ -590,7 +594,7 @@ class HybridFarmSemantics:
 
         def tick(state, t):
             with jax.named_scope("background"):
-                dfx = drive[(t + offsets) % T]                # (K, N)
+                dfx = drive_at(state, t)                      # (K, N)
             with jax.named_scope("neuron"):
                 v, ref, spk = lif_step_ref(state["v"], state["ref"], dfx,
                                            **ens.lif)
@@ -637,7 +641,8 @@ class HybridFarmSemantics:
                     "e_pl3_neuron": e_pl3["neuron"],
                     "e_pl3_synapse": e_pl3["synapse"] + e_mac,
                 }
-            new_state = {"v": v, "ref": ref, "spike_buf": spk_f}
+            new_state = {"v": v, "ref": ref, "spike_buf": spk_f,
+                         **self.stimulus.carry(state)}
             return new_state, rec
 
         return tick
@@ -646,7 +651,8 @@ class HybridFarmSemantics:
 def hybrid_farm_graph(n_pairs: int, n_neurons: int = 32, hidden: int = 16,
                       table_ticks: int = 256, seed: int = 0,
                       amplitude: float = 0.8, period_ticks: float = 97,
-                      phase_step_ticks: int = 17) -> NetGraph:
+                      phase_step_ticks: int = 17,
+                      stimulus=None) -> NetGraph:
     """``n_pairs`` independent NEF -> event-MAC channels as one graph
     (2 * n_pairs populations).  All NEF populations are laid out before
     all MLP populations, so channel k's projection crosses a long stretch
@@ -655,12 +661,14 @@ def hybrid_farm_graph(n_pairs: int, n_neurons: int = 32, hidden: int = 16,
     The drive is a sine of ``amplitude`` and ``period_ticks``, tabled
     over ``table_ticks`` ticks (the table wraps; a whole number of
     periods wraps without a jump); channel k reads it
-    ``k * phase_step_ticks`` ticks ahead.
+    ``k * phase_step_ticks`` ticks ahead.  A ``stimulus``
+    (``repro.chip.stimulus``) replaces that table: the served farm
+    streams each session's waveform.
     """
     ens = build_ensemble(n_neurons, 1, seed=seed)
-    t = np.arange(table_ticks)
-    x = amplitude * np.sin(2 * np.pi * t / period_ticks)[:, None]
-    drive_fx = encode_drive(ens, x, use_mac=True)
+    if stimulus is None:
+        stimulus = TableStimulus.sine(ens, table_ticks, amplitude,
+                                      period_ticks, phase_step_ticks)
     rng = np.random.default_rng(seed)
     w = jnp.asarray(rng.standard_normal((n_neurons, hidden)) * 0.1,
                     jnp.float32)
@@ -676,9 +684,8 @@ def hybrid_farm_graph(n_pairs: int, n_neurons: int = 32, hidden: int = 16,
     projs = [Projection(src=f"nef{k}", dst=f"mlp{k}", payload=GRADED,
                         bits_per_packet=16 * n_neurons, delay_ticks=1)
              for k in range(n_pairs)]
-    sem = HybridFarmSemantics(ens=ens, w_eff=w_eff, drive_fx=drive_fx,
-                              n_pairs=n_pairs,
-                              phase_step_ticks=phase_step_ticks)
+    sem = HybridFarmSemantics(ens=ens, w_eff=w_eff, stimulus=stimulus,
+                              n_pairs=n_pairs)
     return NetGraph(populations=pops, projections=projs, semantics=sem,
                     name=f"hybrid_farm{n_pairs}")
 

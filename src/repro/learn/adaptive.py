@@ -37,7 +37,8 @@ import numpy as np
 from repro.chip.chip import ChipSim, chip_power_table
 from repro.chip.compile import ChipProgram, compile as compile_graph
 from repro.chip.graph import GRADED, NetGraph, Population, Projection
-from repro.core.nef import build_ensemble, encode_drive
+from repro.chip.stimulus import TableStimulus
+from repro.core.nef import build_ensemble
 from repro.kernels.explog.ref import FX_ONE
 from repro.kernels.lif.ops import lif_params_fx
 from repro.kernels.lif.ref import lif_step_ref
@@ -66,17 +67,19 @@ class AdaptiveControlSemantics:
       engine's PES step consumes — reported per slot under
       ``learn/nef{k}->plant{k}/err`` next to the pre spikes.
 
+    The reference and its drive come from ``stimulus``
+    (``repro.chip.stimulus``): a table baked in at build time, or the
+    session's window streamed through the carry when the loop is served.
+
     With ``plastic=False`` the projections carry no rule and the decode
     uses ``frozen_decoders`` — the frozen twin the learning benchmark
     measures tick-time overhead against.
     """
     ens: object                          # core.nef.Ensemble (shared build)
-    drive_fx: jnp.ndarray                # (T, N) s16.15 encode of r(t)
-    r_table: np.ndarray                  # (T,) reference signal
+    stimulus: object                     # chip.stimulus source of r(t)
     n_channels: int
     plastic: bool = True
     tau_plant_ticks: float = 4.0
-    bits_per_value: int = 32
     t_sys_s: float = 1e-3
     frozen_decoders: Optional[np.ndarray] = None   # (N,) used if frozen
 
@@ -97,7 +100,8 @@ class AdaptiveControlSemantics:
               "u_filt": jnp.zeros(K, jnp.float32),
               "u_buf": jnp.zeros(K, jnp.float32),     # nef -> plant wire
               "err_buf": jnp.zeros(K, jnp.float32),   # plant -> nef wire
-              "y": jnp.zeros(K, jnp.float32)}
+              "y": jnp.zeros(K, jnp.float32),
+              **self.stimulus.init_state()}
         if self.plastic:
             st["learn"] = init_learn_state(program)
         return st
@@ -106,11 +110,7 @@ class AdaptiveControlSemantics:
         ens = self.ens
         K, N = self.n_channels, ens.n_neurons
         P = program.n_pes
-        drive = self.drive_fx
-        r = jnp.asarray(self.r_table, jnp.float32)
-        T = drive.shape[0]
-        # co-prime phase offsets decorrelate the channels
-        offsets = jnp.asarray((np.arange(K) * 31) % T)
+        drive_at, r_at = self.stimulus.reader(K)
         alpha_syn = float(np.exp(-1.0 / ens.tau_syn_ticks))
         k_p = 1.0 / self.tau_plant_ticks
         nef_np, pla_np = self._pe_ids(program)
@@ -123,8 +123,7 @@ class AdaptiveControlSemantics:
                 else np.zeros(N), jnp.float32)
 
         def tick(state, t):
-            tt = (t + offsets) % T
-            dfx = drive[tt]                                   # (K, N)
+            dfx = drive_at(state, t)                          # (K, N)
             v, ref, spk = lif_step_ref(state["v"], state["ref"], dfx,
                                        **ens.lif)
             spk_f = spk.astype(jnp.float32)                   # (K, N)
@@ -143,7 +142,7 @@ class AdaptiveControlSemantics:
 
             # plant consumes LAST tick's control (1-tick transport)
             y = state["y"] + (state["u_buf"] - state["y"]) * k_p
-            r_now = r[tt]                                     # (K,)
+            r_now = r_at(state, t)                            # (K,)
             e_now = y - r_now
             e_arr = state["err_buf"]     # error arriving at nef this tick
 
@@ -181,7 +180,8 @@ class AdaptiveControlSemantics:
                     rec[f"learn/{name}/err"] = e_arr[k][None]
 
             new_state = {"v": v, "ref": ref, "u_filt": u, "u_buf": u,
-                         "err_buf": e_now, "y": y}
+                         "err_buf": e_now, "y": y,
+                         **self.stimulus.carry(state)}
             if self.plastic:
                 new_state["learn"] = state["learn"]   # engine advances it
             return new_state, rec
@@ -194,17 +194,21 @@ def adaptive_control_graph(n_channels: int = 4, n_neurons: int = 100,
                            learning_rate: float = 3e-6,
                            plastic: bool = True,
                            tau_plant_ticks: float = 4.0,
-                           period: int = 2048, amp: float = 0.8) -> NetGraph:
+                           period: int = 2048, amp: float = 0.8,
+                           stimulus=None) -> NetGraph:
     """K closed adaptive-control loops as one graph (2K populations).
 
-    The reference r(t) is a slow sine (Yan et al.'s stimulus class); its
-    MAC-encoded drive table is shared by all channels at co-prime phase
-    offsets.  ``plastic=False`` builds the frozen twin (no rules, fixed
-    decoders) for overhead baselines."""
+    By default the reference r(t) is a slow sine (Yan et al.'s stimulus
+    class) of ``amp`` and ``period`` over ``n_ticks``; its MAC-encoded
+    drive table is shared by all channels at co-prime phase offsets (31
+    ticks apart).  A ``stimulus`` (``repro.chip.stimulus``) replaces
+    that table: the served loop streams each session's reference.
+    ``plastic=False`` builds the frozen twin (no rules, fixed decoders)
+    for overhead baselines."""
     ens = build_ensemble(n_neurons, 1, seed=seed)
-    t = np.arange(n_ticks)
-    r = amp * np.sin(2 * np.pi * t / period)
-    drive_fx = encode_drive(ens, r[:, None], use_mac=True)
+    if stimulus is None:
+        stimulus = TableStimulus.sine(ens, n_ticks, amp, period,
+                                      phase_step=31)
 
     nef_sram = n_neurons * (3 * 4 + 2 * 4) + n_neurons * 4 * 2   # + dec/tr
     plant_sram = 64
@@ -220,7 +224,7 @@ def adaptive_control_graph(n_channels: int = 4, n_neurons: int = 100,
                            bits_per_packet=32, delay_ticks=1)
                 for k in range(n_channels)])
     sem = AdaptiveControlSemantics(
-        ens=ens, drive_fx=drive_fx, r_table=r, n_channels=n_channels,
+        ens=ens, stimulus=stimulus, n_channels=n_channels,
         plastic=plastic, tau_plant_ticks=tau_plant_ticks)
     return NetGraph(populations=pops, projections=projs, semantics=sem,
                     name=f"adaptive_control{n_channels}"

@@ -1,8 +1,6 @@
 from repro.serve.fleet.engine import FleetEngine
 from repro.serve.fleet.scenarios import (SCENARIOS, ServedScenario,
                                          SineStream, adaptive_scenario,
-                                         blank_stim, kws_scenario,
-                                         served_adaptive_graph,
-                                         served_kws_graph)
+                                         kws_scenario)
 from repro.serve.fleet.sessions import Session, SessionTable
 from repro.serve.fleet.traffic import PoissonTraffic, SessionSpec

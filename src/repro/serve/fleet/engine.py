@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.chip.chip import ChipSim
 from repro.chip.compile import compile as compile_graph
+from repro.chip.stimulus import blank_stim
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.core.dvfs import QueueDVFS
 from repro.obs.health import SloMonitor, default_fleet_slos
@@ -44,7 +45,7 @@ from repro.obs.metrics import (MetricsRegistry, device_metrics_for,
                                make_device_metrics)
 from repro.obs.probes import make_batched_probe_step, resolve_probes
 from repro.obs.spans import SpanLog, validate_spans
-from repro.serve.fleet.scenarios import ServedScenario, blank_stim
+from repro.serve.fleet.scenarios import ServedScenario
 from repro.serve.fleet.sessions import Session, SessionTable
 from repro.serve.queue import RequestQueue, percentiles
 

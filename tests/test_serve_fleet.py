@@ -12,6 +12,8 @@ Anchors, strongest first:
   or, via ``repro.ckpt``, in a different one — produce outputs identical
   to an uninterrupted run;
 * width follows the queue's offered load through the batch levels;
+* a streamed stimulus equal to a baked table drives each network's one
+  tick to the same records, bitwise;
 * both served scenarios (adaptive control, KWS hybrid farm) and the
   board-compiled program serve end-to-end under Poisson traffic.
 """
@@ -20,7 +22,11 @@ import pytest
 
 from repro.chip.chip import ChipSim
 from repro.chip.compile import compile as compile_graph
+from repro.chip.stimulus import StreamedStimulus, TableStimulus
+from repro.chip.workloads import hybrid_farm_graph
 from repro.core.dvfs import QueueDVFS
+from repro.core.nef import build_ensemble
+from repro.learn.adaptive import adaptive_control_graph
 from repro.serve.fleet import (FleetEngine, PoissonTraffic, Session,
                                SessionTable, adaptive_scenario,
                                kws_scenario)
@@ -150,9 +156,11 @@ def test_poisson_traffic_deterministic_and_exhausts():
 
 # ------------------------------------------------------- bitwise anchors
 
-def test_fleet_of_one_bitwise_matches_chipsim(adaptive_sc):
+@pytest.mark.parametrize("name", ["adaptive", "kws"])
+def test_fleet_of_one_bitwise_matches_chipsim(adaptive_sc, name):
     """Acceptance anchor: w=1 fleet == plain ChipSim.run, bitwise."""
-    sc = adaptive_sc
+    sc = adaptive_sc if name == "adaptive" else kws_scenario(
+        n_pairs=2, n_neurons=32, hidden=8, n_keywords=3)
     T = 3 * TC
     sess = _solo(sc, seed=41, total_ticks=T)
     # plain engine: same program shape, whole stimulus preloaded
@@ -160,6 +168,31 @@ def test_fleet_of_one_bitwise_matches_chipsim(adaptive_sc):
     recs = ChipSim(compile_graph(sc.graph(T, stim))).run(T)
     for k in sc.output_keys:
         np.testing.assert_array_equal(sess.outputs[k], np.asarray(recs[k]))
+
+
+@pytest.mark.parametrize("network", ["adaptive", "farm"])
+def test_streamed_stimulus_matches_table(network):
+    """One tick per network: the window streamed through ``state["stim"]``
+    drives it exactly as the same table baked in, read at phase step 0
+    (every channel on the same tick), so served and benchmarked programs
+    run the same semantics."""
+    ens = build_ensemble(32, 1, seed=0)
+    table = TableStimulus.sine(ens, 48, 0.8, 24.0, phase_step=0)
+    window = {"r": np.asarray(table.r, np.float32),
+              "drive": np.asarray(table.drive)}
+    if network == "adaptive":
+        build = lambda stim: adaptive_control_graph(
+            2, 32, seed=0, plastic=True, stimulus=stim)
+    else:
+        build = lambda stim: hybrid_farm_graph(2, 32, 8, seed=0,
+                                               stimulus=stim)
+    recs = [ChipSim(compile_graph(build(stim))).run(64)
+            for stim in (table, StreamedStimulus(window))]
+    assert sorted(recs[0]) == sorted(recs[1])
+    for k in recs[0]:
+        np.testing.assert_array_equal(np.asarray(recs[0][k]),
+                                      np.asarray(recs[1][k]), err_msg=k)
+    assert np.asarray(recs[0]["n_spk"]).sum() > 0
 
 
 def test_preemption_and_resume_invisible(adaptive_sc):

@@ -155,7 +155,7 @@ def test_farm_engine_step_compiles_at_cell_size(one_chip, farm4x12):
         _sds((), jnp.int32, one_chip)).compile()
     mem = compiled.memory_analysis()
     param_bytes = sum(p.size * p.dtype.itemsize for p in params)
-    assert param_bytes >= (sem.drive_fx.nbytes + sem.w_eff.nbytes
+    assert param_bytes >= (sem.stimulus.drive.nbytes + sem.w_eff.nbytes
                            + 4 * farm4x12.n_pes * farm4x12.noc.n_links)
     assert mem.argument_size_in_bytes >= param_bytes
     assert mem.generated_code_size_in_bytes < param_bytes // 4
