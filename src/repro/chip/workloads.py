@@ -507,6 +507,24 @@ def hybrid_graph(n_neurons: int = 256, hidden: int = 64,
                     name="hybrid_nef_mlp")
 
 
+def check_farm_placement(nef_ids: np.ndarray, mlp_ids: np.ndarray,
+                         n_pes: int) -> None:
+    """Raise ``ValueError`` unless channel k's NEF PE is k and its MLP
+    PE is K + k, on P == 2K PEs.  Every compile of ``hybrid_farm_graph``
+    lays a farm out so: populations take logical PEs in graph order
+    (``chip.compile``, ``board.route.place_partition``; refinement and
+    routeopt move only coordinates), and the graph lists nef0..nef{K-1},
+    then mlp0..mlp{K-1}, one tile each.  The farm's per-PE rows are then
+    the concatenation of its (K,) NEF and (K,) MLP values."""
+    K = len(nef_ids)
+    if not (n_pes == 2 * K and np.array_equal(nef_ids, np.arange(K))
+            and np.array_equal(mlp_ids, K + np.arange(K))):
+        raise ValueError(
+            f"hybrid farm of {K} channels on {n_pes} PEs: expected NEF "
+            f"PEs 0..{K - 1} and MLP PEs {K}..{2 * K - 1}, the layout "
+            "every compile of hybrid_farm_graph gives")
+
+
 @dataclass
 class HybridFarmSemantics:
     """K independent NEF -> event-MAC channels ticking in lockstep — the
@@ -571,26 +589,14 @@ class HybridFarmSemantics:
         P = program.n_pes
         drive_at, _ = self.stimulus.reader(K)
         nef_np, mlp_np = self._pe_ids(program)
-        n_neur = jnp.zeros(P).at[jnp.asarray(nef_np)].set(
-            float(N)).astype(jnp.int32)
+        check_farm_placement(nef_np, mlp_np, P)
+        n_neur = jnp.concatenate([jnp.full(K, N, jnp.int32),
+                                  jnp.zeros(K, jnp.int32)])
         w_eff = self.w_eff
-        # static placement permutation: every per-PE record row is (nef
-        # values | mlp values | 0 elsewhere), so one gather through this
-        # (P,) index table replaces a scatter per record key — scatters
-        # with 2K dynamic indices were the farm tick's dominant cost at
-        # 4096 PEs, a gather of the concatenated channel values is fused
-        # elementwise.  Bitwise-identical: same values land on the same
-        # PEs, everything else is exactly 0.
-        perm_np = np.full(P, 2 * K, np.int64)
-        perm_np[nef_np] = np.arange(K)
-        perm_np[mlp_np] = K + np.arange(K)
-        perm = jnp.asarray(perm_np)
-        zk = jnp.zeros(K, jnp.float32)
 
         def place2(nef_vals, mlp_vals):
             """(K,) nef values + (K,) mlp values -> (P,) per-PE row."""
-            return jnp.concatenate(
-                [nef_vals, mlp_vals, jnp.zeros(1, jnp.float32)])[perm]
+            return jnp.concatenate([nef_vals, mlp_vals])
 
         def tick(state, t):
             with jax.named_scope("background"):
@@ -613,6 +619,7 @@ class HybridFarmSemantics:
                 bits_in = self.bits_per_spike * n_arr
 
             with jax.named_scope("route"):
+                zk = jnp.zeros(K, jnp.float32)
                 packets = place2(active, zk)
                 payload_bits = place2(bits_out, zk)
                 fifo = place2(jnp.full(K, float(N)), n_arr)

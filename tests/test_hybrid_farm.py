@@ -13,7 +13,8 @@ import pytest
 
 from repro.board import BoardSpec, compile_board, compile_for_board
 from repro.chip.chip import ChipSim
-from repro.chip.workloads import hybrid_farm_board_graph
+from repro.chip.workloads import (check_farm_placement,
+                                  hybrid_farm_board_graph)
 
 ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
@@ -72,6 +73,51 @@ def test_small_farm_comparison_sees_each_record_kind(small_farm, key):
     checks = hybrid_farm.compare(ref, bad)
     assert (checks["int_mismatch"] > 0
             or checks["float_rel_gap"] > CONFIG["limits"]["float_rel_gap"])
+
+
+def _shuffled(n_pes: int, k: int) -> tuple:
+    pes = np.random.default_rng(5).permutation(n_pes)
+    return pes[:k], pes[k:2 * k]
+
+
+# channel k's NEF and MLP PE ids for K = 5 channels on P PEs
+PLACEMENTS = {
+    "graph_order": (np.arange(5), 5 + np.arange(5), 10),
+    "tail": (np.arange(5), 5 + np.arange(5), 13),
+    "mlp_first": (5 + np.arange(5), np.arange(5), 10),
+    "gap": (2 + np.arange(5), 9 + np.arange(5), 16),
+    "shuffled": (*_shuffled(12, 5), 12),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(PLACEMENTS))
+def test_farm_placement_check(layout):
+    """The farm's per-PE rows concatenate its NEF and MLP values, so it
+    takes only the layout every compile gives it: NEF PEs 0..K-1, MLP
+    PEs K..2K-1 and no PE after them."""
+    nef_ids, mlp_ids, n_pes = PLACEMENTS[layout]
+    if layout == "graph_order":
+        check_farm_placement(nef_ids, mlp_ids, n_pes)
+    else:
+        with pytest.raises(ValueError, match="expected NEF PEs 0..4"):
+            check_farm_placement(nef_ids, mlp_ids, n_pes)
+
+
+@pytest.mark.parametrize("order", ["interleaved", "mlp_first"])
+def test_farm_tick_rejects_a_reordered_graph(order):
+    """A farm graph whose populations were reordered by hand compiles,
+    but its tick refuses the placement instead of misplacing rows."""
+    from dataclasses import replace
+    from repro.chip.compile import compile as compile_graph
+    from repro.chip.workloads import hybrid_farm_graph
+    graph = hybrid_farm_graph(n_pairs=3, n_neurons=8, hidden=4)
+    nef, mlp = graph.populations[:3], graph.populations[3:]
+    pops = ([p for pair in zip(nef, mlp) for p in pair]
+            if order == "interleaved" else mlp + nef)
+    program = compile_graph(replace(graph, populations=pops))
+    ChipSim(compile_graph(graph)).make_stepper()
+    with pytest.raises(ValueError, match="expected NEF PEs 0..2"):
+        ChipSim(program).make_stepper()
 
 
 def test_board_entry_compiles_the_4x12_board_from_the_graph_alone():

@@ -164,6 +164,18 @@ def test_farm_engine_step_compiles_at_cell_size(one_chip, farm4x12):
                      compiled.as_text())
 
 
+def test_farm_step_gathers_no_row_at_cell_size(one_chip, farm4x12):
+    """The cell's farm builds its f32[1536] per-PE rows by concatenation:
+    its v5e tick gathers nothing but the drive table's rows."""
+    init, step, params = ChipSim(farm4x12).make_stepper()
+    as_sds = lambda x: _sds(x.shape, x.dtype, one_chip)
+    hlo = jax.jit(step).lower(
+        jax.tree.map(as_sds, params), jax.tree.map(as_sds, init),
+        _sds((), jnp.int32, one_chip)).compile().as_text()
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", hlo)
+    assert set(gathers) == {"s32[768,512]"}     # the drive lookup
+
+
 def _constant_sizes(hlo: str) -> list:
     """Element counts of the constants an optimised module holds."""
     return [int(np.prod([int(d) for d in dims.split(",") if d]))
